@@ -28,21 +28,23 @@ from typing import Callable, Optional
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.dfp import exp2i as _exp2i
 
-try:  # TPU-specific scratch allocator; absent on exotic installs is fine
-    from jax.experimental.pallas import tpu as pltpu
-except Exception:  # pragma: no cover
-    pltpu = None
+_FUSED_COMPILER_PARAMS = pltpu.CompilerParams(
+    dimension_semantics=("parallel", "parallel", "arbitrary")
+)
 
-try:  # scheduling hints: the class name moved across jax releases
-    _cp = getattr(pltpu, "CompilerParams", None) or pltpu.TPUCompilerParams
-    _FUSED_COMPILER_PARAMS = _cp(
-        dimension_semantics=("parallel", "parallel", "arbitrary")
-    )
-except Exception:  # pragma: no cover
-    _FUSED_COMPILER_PARAMS = None
+
+def interpret_mode() -> bool:
+    """Whether Pallas kernels run in interpret mode: everywhere but a TPU.
+
+    The one place the platform decides this.  On the TPU every kernel
+    compiles through Mosaic; elsewhere (CPU tests) the interpreter runs the
+    same kernel bodies so parity tests see the exact semantics."""
+    return jax.default_backend() != "tpu"
+
 
 TERNARY_PER_WORD = 16
 INT4_PER_WORD = 8
@@ -54,8 +56,8 @@ def decode2_tile(words: jnp.ndarray, bk: int) -> jnp.ndarray:
     """(bk/16, bn) uint32 -> (bk, bn) int8 in {-1, 0, 1}."""
     lanes = []
     for i in range(TERNARY_PER_WORD):
-        c = (words >> (2 * i)) & jnp.uint32(3)
-        lanes.append((((c + 1) & 3).astype(jnp.int8) - 1))
+        c = ((words >> (2 * i)) & jnp.uint32(3)).astype(jnp.int32)
+        lanes.append((((c + 1) & 3) - 1).astype(jnp.int8))
     return jnp.stack(lanes, axis=1).reshape(bk, words.shape[-1])
 
 
@@ -63,8 +65,8 @@ def decode4_tile(words: jnp.ndarray, bk: int) -> jnp.ndarray:
     """(bk/8, bn) uint32 -> (bk, bn) int8 in [-8, 7]."""
     lanes = []
     for i in range(INT4_PER_WORD):
-        c = ((words >> (4 * i)) & jnp.uint32(0xF)).astype(jnp.int8)
-        lanes.append(jnp.where(c >= 8, c - 16, c))
+        c = ((words >> (4 * i)) & jnp.uint32(0xF)).astype(jnp.int32)
+        lanes.append(jnp.where(c >= 8, c - 16, c).astype(jnp.int8))
     return jnp.stack(lanes, axis=1).reshape(bk, words.shape[-1])
 
 
@@ -291,8 +293,6 @@ def fused_qmm_call(
     interpret: bool = False,
 ) -> jax.Array:
     """One pallas_call for quantize-prologue + qmatmul + scale/bias/act."""
-    if pltpu is None:  # pragma: no cover
-        raise RuntimeError("fused qdense kernels need jax.experimental.pallas.tpu")
     m, k = x.shape
     bm, bn = min(block_m, m), min(block_n, n)
     bk = min(block_k, k)

@@ -18,15 +18,11 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-try:
-    from jax.experimental.pallas import tpu as pltpu
-
-    _COMPILER_PARAMS = pltpu.CompilerParams(
-        dimension_semantics=("parallel", "parallel", "arbitrary")
-    )
-except Exception:  # pragma: no cover
-    _COMPILER_PARAMS = None
+_COMPILER_PARAMS = pltpu.CompilerParams(
+    dimension_semantics=("parallel", "parallel", "arbitrary")
+)
 
 NEG_INF = -1e30
 

@@ -1,7 +1,7 @@
 """Flash-decode over a packed quantized KV cache: the S == 1 special case.
 
 The kernel itself lives in ``kernels/flash_prefill.py`` as the unified
-``flash_attend`` (grid (B, Kh, S/bq, T/bk), online softmax, in-VMEM
+``flash_attend`` (grid (B, S/bq, T/bk), online softmax, in-VMEM
 dequant of the packed kv_bf16 / kv_int8 / kv_mx leaves); a decode step is
 a one-row chunk whose start IS its query position.  This module keeps the
 original decode-shaped entry point -- (B, Kh, G, hd) queries, no S axis --

@@ -2,17 +2,18 @@
 
 ``flash_attend`` generalizes the PR-7 flash-decode kernel from S == 1 to
 whole prefill chunks: a (B, S, Kh, G, hd) query block attends against the
-full cache with grid (B, Kh, S/bq, T/bk), the KV axis innermost
-("arbitrary").  Each (batch, kv-head, query-block) program revisits its
-output tile across KV tiles carrying running (m, l, acc) online-softmax
-statistics in VMEM scratch -- the (S, T) score plane never exists, and the
-cache streams from HBM exactly once per chunk, *packed*:
+full cache with grid (B, S/bq, T/bk), the KV axis innermost
+("arbitrary").  Each (batch, query-block) program holds every kv head and
+revisits its output tile across KV tiles carrying running (m, l, acc)
+online-softmax statistics per head in VMEM scratch -- the (S, T) score
+plane never exists, and the cache streams from HBM once per query block,
+*packed*:
 
   * kv_bf16  tiles load as bf16 and cast,
-  * kv_int8  tiles load int8 mantissas + a (bk, 1) exponent column and
+  * kv_int8  tiles load int8 mantissas + a (bk, Kh, 1) exponent block and
     dequantize in-VMEM via exact power-of-two scales (``dfp.exp2i``),
-  * kv_mx    tiles load nibble-packed int4 mantissas (bk, hd/2) + one
-    exponent per 32-token block (bk/32, 1), unpack and shift in-VMEM.
+  * kv_mx    tiles load nibble-packed int4 mantissas (bk, Kh, hd/2) + one
+    exponent per 32-token block (bk/32, Kh, 1), unpack and shift in-VMEM.
 
 All G query heads of a KV group ride in one tile as bq*G rows, so GQA and
 MHA (G == 1) share the layout.  Masking is positional per query row: the
@@ -40,38 +41,37 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from repro.core import dfp
+from repro.kernels._common import interpret_mode
 from repro.models.kv_cache import MX_KV_BLOCK
 
-try:  # class name moved across JAX versions (see kernels/_common.py)
-    from jax.experimental.pallas import tpu as pltpu
-
-    _CP_CLS = getattr(pltpu, "CompilerParams", None) or pltpu.TPUCompilerParams
-    _COMPILER_PARAMS = _CP_CLS(
-        dimension_semantics=("parallel", "parallel", "parallel", "arbitrary")
-    )
-except Exception:  # pragma: no cover
-    _COMPILER_PARAMS = None
+_COMPILER_PARAMS = pltpu.CompilerParams(
+    dimension_semantics=("parallel", "parallel", "arbitrary")
+)
 
 NEG_INF = -1e30
 
 
-def _dequant_tile(ref, eref, fmt: str, bk: int, hd: int) -> jax.Array:
-    """One (bk, hd) f32 KV tile from packed VMEM blocks."""
-    tile = ref[0, :, 0, :]
+def _dequant_tile(ref, eref, h: int, fmt: str, bk: int, hd: int) -> jax.Array:
+    """Head ``h``'s (bk, hd) f32 KV tile from a packed all-heads VMEM block."""
+    tile = ref[:, h, :]
     if fmt == "kv_bf16":
         return tile.astype(jnp.float32)
     if fmt == "kv_int8":
-        e = eref[0, :, 0, :]  # (bk, 1) int8
+        e = eref[:, h, :]  # (bk, 1) int8
         return tile.astype(jnp.float32) * dfp.exp2i(e)
     # kv_mx: unpack nibble pairs along head_dim, one exponent per 32 tokens
     b32 = tile.astype(jnp.int32)  # (bk, hd//2) uint8 widened
     lo, hi = b32 & 0xF, (b32 >> 4) & 0xF
     lo = jnp.where(lo >= 8, lo - 16, lo)
     hi = jnp.where(hi >= 8, hi - 16, hi)
-    codes = jnp.stack([lo, hi], axis=-1).reshape(bk, hd).astype(jnp.float32)
-    e = eref[0, :, 0, :]  # (bk // 32, 1) int8
+    # lanes come out [even channels | odd channels]: flash_attend feeds the
+    # queries in that order and restores it on the output (an in-kernel
+    # lane interleave costs Mosaic minutes of compile)
+    codes = jnp.concatenate([lo, hi], axis=-1).astype(jnp.float32)
+    e = eref[:, h, :]  # (bk // 32, 1) int8
     nb = bk // MX_KV_BLOCK
     e_tok = jnp.broadcast_to(
         e.reshape(nb, 1, 1), (nb, MX_KV_BLOCK, 1)
@@ -79,16 +79,16 @@ def _dequant_tile(ref, eref, fmt: str, bk: int, hd: int) -> jax.Array:
     return codes * dfp.exp2i(e_tok)
 
 
-def _kernel(*refs, fmt, bq, bk, g, hd, scale):
+def _kernel(qs_ref, vl_ref, win_ref, *refs, fmt, kh, rows, bk, g, hd, scale):
     if fmt == "kv_bf16":
-        (q_ref, k_ref, v_ref, qs_ref, vl_ref, win_ref,
-         o_ref, m_ref, l_ref, acc_ref) = refs
+        q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref = refs
         ke_ref = ve_ref = None
     else:
-        (q_ref, k_ref, v_ref, ke_ref, ve_ref, qs_ref, vl_ref, win_ref,
+        (q_ref, k_ref, v_ref, ke_ref, ve_ref,
          o_ref, m_ref, l_ref, acc_ref) = refs
-    q_idx = pl.program_id(2)
-    kv_idx = pl.program_id(3)
+    b_idx = pl.program_id(0)
+    q_idx = pl.program_id(1)
+    kv_idx = pl.program_id(2)
 
     @pl.when(kv_idx == 0)
     def _init():
@@ -96,38 +96,41 @@ def _kernel(*refs, fmt, bq, bk, g, hd, scale):
         l_ref[...] = jnp.zeros_like(l_ref)
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    rows = bq * g  # all G heads of the group ride as interleaved rows
-    q = q_ref[0, :, 0].reshape(rows, hd).astype(jnp.float32) * scale
-    kf = _dequant_tile(k_ref, ke_ref, fmt, bk, hd)  # (bk, hd)
-    s = jax.lax.dot_general(
-        q, kf, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-    )  # (rows, bk)
-
+    # all G heads of a kv group ride as interleaved rows: row r of query
+    # block q_idx is chunk position (q_idx * rows + r) // G
     k_pos = kv_idx * bk + jax.lax.broadcasted_iota(jnp.int32, (1, bk), 1)
-    row = jax.lax.broadcasted_iota(jnp.int32, (rows, 1), 0)
-    q_pos = qs_ref[0, 0] + q_idx * bq + row // g  # (rows, 1) absolute
-    valid, win = vl_ref[0, 0], win_ref[0, 0]
+    row = q_idx * rows + jax.lax.broadcasted_iota(jnp.int32, (rows, 1), 0)
+    q_pos = qs_ref[b_idx] + row // g  # (rows, 1) absolute
+    valid, win = vl_ref[b_idx], win_ref[0]
     ok = (k_pos < valid) & (k_pos <= q_pos) & (q_pos - k_pos < win)
-    s = jnp.where(ok, s, NEG_INF)  # (rows, bk)
 
-    m_prev, l_prev = m_ref[...], l_ref[...]  # (rows, 1)
-    m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
-    p = jnp.exp(s - m_new)
-    corr = jnp.exp(m_prev - m_new)
-    l_new = l_prev * corr + jnp.sum(p, axis=-1, keepdims=True)
-    vf = _dequant_tile(v_ref, ve_ref, fmt, bk, hd)  # (bk, hd)
-    pv = jax.lax.dot_general(
-        p, vf, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
-    )
-    acc_ref[...] = acc_ref[...] * corr + pv
-    m_ref[...] = m_new
-    l_ref[...] = l_new
+    for h in range(kh):  # the block holds every kv head of these tokens
+        q = q_ref[h].astype(jnp.float32) * scale  # (rows, hd)
+        kf = _dequant_tile(k_ref, ke_ref, h, fmt, bk, hd)  # (bk, hd)
+        s = jax.lax.dot_general(
+            q, kf, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32,
+        )  # (rows, bk)
+        s = jnp.where(ok, s, NEG_INF)
 
-    @pl.when(kv_idx == pl.num_programs(3) - 1)
+        m_prev, l_prev = m_ref[h], l_ref[h]  # (rows, 1)
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+        p = jnp.exp(s - m_new)
+        corr = jnp.exp(m_prev - m_new)
+        vf = _dequant_tile(v_ref, ve_ref, h, fmt, bk, hd)  # (bk, hd)
+        pv = jax.lax.dot_general(
+            p, vf, (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32,
+        )
+        l_ref[h] = l_prev * corr + jnp.sum(p, axis=-1, keepdims=True)
+        acc_ref[h] = acc_ref[h] * corr + pv
+        m_ref[h] = m_new
+
+    @pl.when(kv_idx == pl.num_programs(2) - 1)
     def _finalize():
-        o_ref[0, :, 0] = (
+        o_ref[...] = (
             acc_ref[...] / jnp.maximum(l_ref[...], 1e-30)
-        ).reshape(bq, g, hd).astype(o_ref.dtype)
+        ).astype(o_ref.dtype)
 
 
 def pick_kv_block(t: int, fmt: str, want: int = 128) -> int:
@@ -148,10 +151,14 @@ def pick_q_block(s: int, g: int, want: int = 64) -> int:
     """Largest divisor of S keeping bq*G query rows near ``want``.
 
     The kernel flattens a query block to bq*G rows (all G heads of the KV
-    group), so the row budget -- not bq alone -- is what VMEM sees."""
+    group), so the row budget -- not bq alone -- is what VMEM sees.  A
+    block short of the whole chunk keeps bq*G a multiple of 8 (the TPU's
+    sublane tile); when no divisor does, the whole chunk is one block."""
     b = min(s, max(1, want // g))
-    while s % b:
+    while s % b or (b != s and (b * g) % 8):
         b -= 1
+        if b == 0:
+            return s
     return b
 
 
@@ -175,50 +182,71 @@ def flash_attend(
     Query row s of batch b sits at absolute position q_start[b] + s (the
     contiguous-chunk contract); masking is causal against that offset plus
     the fill level and sliding window.  S == 1 with q_start = q_pos is
-    exactly the flash-decode special case."""
+    exactly the flash-decode special case.
+
+    TPU layout: a grid step owns one (batch row, query block, KV tile) and
+    every kv head in it.  A cache block is then (bk, Kh, lanes) -- its last
+    two dimensions whole, as Mosaic requires -- so the cache is read in
+    place, once per query block.  The queries are regrouped to
+    (B, Kh, S*G, hd) (a chunk-sized copy), and the per-row scalars ride in
+    SMEM as scalar-prefetch operands."""
     b, s, kh, g, hd = q.shape
     t = k.shape[1]
     if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+        interpret = interpret_mode()
     bq = pick_q_block(s, g, block_q)
     bk = pick_kv_block(t, fmt, block_k)
+    rows = bq * g
     scale = hd**-0.5
 
+    if fmt == "kv_mx":  # head_dim in the unpacked tiles' lane order
+        q = jnp.concatenate([q[..., 0::2], q[..., 1::2]], axis=-1)
+    qg = q.transpose(0, 2, 1, 3, 4).reshape(b, kh, s * g, hd)
     q_spec = pl.BlockSpec(
-        (1, bq, 1, g, hd), lambda bi, hi, qi, ji: (bi, qi, hi, 0, 0)
+        (None, kh, rows, hd), lambda bi, qi, ji, *_: (bi, 0, qi, 0)
     )
     kv_spec = pl.BlockSpec(
-        (1, bk, 1, k.shape[-1]), lambda bi, hi, qi, ji: (bi, ji, hi, 0)
+        (None, bk, kh, k.shape[-1]), lambda bi, qi, ji, *_: (bi, ji, 0, 0)
     )
     in_specs = [q_spec, kv_spec, kv_spec]
-    args = [q, k, v]
+    args = [qg, k, v]
     if fmt != "kv_bf16":
         eb = bk if fmt == "kv_int8" else bk // MX_KV_BLOCK
         e_spec = pl.BlockSpec(
-            (1, eb, 1, 1), lambda bi, hi, qi, ji: (bi, ji, hi, 0)
+            (None, eb, kh, 1), lambda bi, qi, ji, *_: (bi, ji, 0, 0)
         )
         in_specs += [e_spec, e_spec]
         args += [ke, ve]
-    scalar_spec = pl.BlockSpec((1, 1), lambda bi, hi, qi, ji: (bi, 0))
-    bcast_spec = pl.BlockSpec((1, 1), lambda bi, hi, qi, ji: (0, 0))
-    in_specs += [scalar_spec, scalar_spec, bcast_spec]
-    args += [q_start, valid, window]
 
     kern = functools.partial(
-        _kernel, fmt=fmt, bq=bq, bk=bk, g=g, hd=hd, scale=scale
+        _kernel, fmt=fmt, kh=kh, rows=rows, bk=bk, g=g, hd=hd, scale=scale
     )
-    return pl.pallas_call(
+    out = pl.pallas_call(
         kern,
-        grid=(b, kh, s // bq, t // bk),
-        in_specs=in_specs,
-        out_specs=q_spec,
-        out_shape=jax.ShapeDtypeStruct((b, s, kh, g, hd), jnp.float32),
-        scratch_shapes=[
-            # running max / denom / accumulator survive the kv axis
-            pltpu.VMEM((bq * g, 1), jnp.float32),
-            pltpu.VMEM((bq * g, 1), jnp.float32),
-            pltpu.VMEM((bq * g, hd), jnp.float32),
-        ],
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3,
+            grid=(b, s // bq, t // bk),
+            in_specs=in_specs,
+            out_specs=q_spec,
+            scratch_shapes=[
+                # running max / denom / accumulator survive the kv axis
+                pltpu.VMEM((kh, rows, 1), jnp.float32),
+                pltpu.VMEM((kh, rows, 1), jnp.float32),
+                pltpu.VMEM((kh, rows, hd), jnp.float32),
+            ],
+        ),
+        out_shape=jax.ShapeDtypeStruct((b, kh, s * g, hd), jnp.float32),
         compiler_params=None if interpret else _COMPILER_PARAMS,
         interpret=interpret,
-    )(*args)
+    )(
+        q_start.reshape(b).astype(jnp.int32),
+        valid.reshape(b).astype(jnp.int32),
+        window.reshape(1).astype(jnp.int32),
+        *args,
+    )
+    out = out.reshape(b, kh, s, g, hd).transpose(0, 2, 1, 3, 4)
+    if fmt == "kv_mx":
+        out = jnp.stack(
+            [out[..., : hd // 2], out[..., hd // 2:]], axis=-1
+        ).reshape(out.shape)
+    return out

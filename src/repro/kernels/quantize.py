@@ -18,16 +18,16 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.dfp import exp2i, qmax
 from repro.kernels._common import m_bucket, pick_block
 
-try:
-    from jax.experimental.pallas import tpu as pltpu
-
-    _COMPILER_PARAMS = pltpu.CompilerParams(dimension_semantics=("parallel",))
-except Exception:  # pragma: no cover
-    _COMPILER_PARAMS = None
+_COMPILER_PARAMS = pltpu.CompilerParams(dimension_semantics=("parallel",))
+# The input and int8 output row blocks, each double-buffered, take at most
+# this much of the TPU's scoped VMEM (16 MiB on a v5e), leaving the rest to
+# the kernel's f32 temporaries: 64 f32 rows of D = 32768 alone need 16 MiB.
+_BLOCK_VMEM_BYTES = 8 << 20
 
 
 def _kernel(x_ref, q_ref, e_ref, *, bits: int):
@@ -57,7 +57,8 @@ def quantize_rows(
     mp = m_bucket(m)
     if mp != m:
         x = jnp.pad(x, ((0, mp - m), (0, 0)))  # zero rows -> q=0, e=0
-    bm = pick_block(mp, block_m)
+    rows = _BLOCK_VMEM_BYTES // (2 * d * (x.dtype.itemsize + 1))
+    bm = pick_block(mp, max(8, min(block_m, rows)))
     kern = functools.partial(_kernel, bits=bits)
     q, e = pl.pallas_call(
         kern,

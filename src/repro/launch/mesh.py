@@ -6,7 +6,17 @@ first jax initialization.
 """
 from __future__ import annotations
 
+import os
+
 import jax
+from jax.sharding import AxisType
+
+
+def _make_mesh(shape, axes) -> jax.sharding.Mesh:
+    """``jax.make_mesh`` with Auto axes: the sharding rules place arrays
+    with constraints and the partitioner propagates the rest, which the
+    default Explicit axes of this JAX reject."""
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False) -> jax.sharding.Mesh:
@@ -18,13 +28,13 @@ def make_production_mesh(*, multi_pod: bool = False) -> jax.sharding.Mesh:
     """
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return _make_mesh(shape, axes)
 
 
 def make_host_mesh(model: int = 1) -> jax.sharding.Mesh:
     """Tiny mesh over the real local devices (tests / examples)."""
     n = jax.device_count()
-    return jax.make_mesh((n // model, model), ("data", "model"))
+    return _make_mesh((n // model, model), ("data", "model"))
 
 
 # Launcher-friendly aliases: dp -> batch parallelism, ep/tp -> the 'model'
@@ -53,47 +63,48 @@ def mesh_spec_sizes(spec: str) -> tuple:
 def parse_mesh_spec(spec: str) -> jax.sharding.Mesh:
     """'dp=2,ep=2' (aliases dp->data, ep/tp->model) -> a live Mesh."""
     pairs = mesh_spec_sizes(spec)
-    return jax.make_mesh(
+    return _make_mesh(
         tuple(s for _, s in pairs), tuple(n for n, _ in pairs)
     )
 
 
-def enable_compile_cache(path: str | None) -> str | None:
-    """Point jax's persistent compilation cache at ``path``.
+# One fixed, git-ignored directory in the checkout: the cache's path is part
+# of what a later process looks up, so it must not move between runs.
+COMPILE_CACHE_DIR = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))))),
+    ".jax_cache",
+)
+
+
+def enable_compile_cache() -> str:
+    """Turn on jax's persistent compilation cache; returns its directory.
 
     Cold-start compile time is a serving SLO: a staged engine compiles the
-    decode tick plus O(log chunk) prefill shapes on boot, all of which are
-    byte-stable for a fixed artifact + mesh, so a warm disk cache turns the
-    second boot's compiles into reads.  Env hygiene mirrors the XLA_FLAGS
-    convention above: an operator-set ``JAX_COMPILATION_CACHE_DIR`` wins
-    when no explicit path is given, and the chosen directory is exported
-    back into the environment so worker subprocesses inherit it.  Returns
-    the directory in use, or None when caching stays off."""
-    import os
-
-    path = path or os.environ.get("JAX_COMPILATION_CACHE_DIR")
-    if not path:
-        return None
-    os.makedirs(path, exist_ok=True)
-    from jax.experimental.compilation_cache import compilation_cache as cc
-
-    cc.set_cache_dir(path)
-    os.environ["JAX_COMPILATION_CACHE_DIR"] = path
-    return path
+    decode tick plus O(log chunk) prefill shapes on boot, all byte-stable
+    for a fixed model + mesh, so a warm disk cache turns the second boot's
+    compiles into reads.  An operator-set ``JAX_COMPILATION_CACHE_DIR`` is
+    jax's own setting and wins: nothing is set in code then.  Otherwise the
+    cache lives at ``COMPILE_CACHE_DIR``."""
+    env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if env:
+        return env
+    jax.config.update("jax_compilation_cache_dir", COMPILE_CACHE_DIR)
+    return COMPILE_CACHE_DIR
 
 
 def preinit_mesh_flag(argv) -> None:
-    """Force the host-platform device count for a ``--mesh`` run.
+    """Give a CPU-only ``--mesh`` run its virtual devices.
 
-    Scans ``argv`` for ``--mesh SPEC`` or ``--mesh=SPEC`` and, when the
-    operator did not set XLA_FLAGS themselves, sets
-    ``--xla_force_host_platform_device_count`` to the mesh size.  Call
-    before the first jax initialization (importing this module is safe: the
-    flag is read at backend-client creation, not import).  Malformed specs
-    are left for the caller's argparse to report."""
-    import os
-
-    if "XLA_FLAGS" in os.environ:
+    Only when ``JAX_PLATFORMS=cpu`` is set (a run pinned to the host, such
+    as a test) and the operator did not set XLA_FLAGS themselves: scans
+    ``argv`` for ``--mesh SPEC`` or ``--mesh=SPEC`` and sets
+    ``--xla_force_host_platform_device_count`` to the mesh size.  On an
+    accelerator the mesh is built from the real devices.  Call before the
+    first jax initialization (importing this module is safe: the flag is
+    read at backend-client creation, not import).  A malformed spec raises
+    here, before jax starts."""
+    if os.environ.get("JAX_PLATFORMS") != "cpu" or "XLA_FLAGS" in os.environ:
         return
     spec = None
     for i, arg in enumerate(argv):
@@ -105,12 +116,9 @@ def preinit_mesh_flag(argv) -> None:
             break
     if spec is None:
         return
-    try:
-        n = 1
-        for _, size in mesh_spec_sizes(spec):
-            n *= size
-    except ValueError:
-        return
+    n = 1
+    for _, size in mesh_spec_sizes(spec):
+        n *= size
     os.environ["XLA_FLAGS"] = (
         f"--xla_force_host_platform_device_count={n}"
     )

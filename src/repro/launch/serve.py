@@ -38,9 +38,9 @@ import argparse
 import sys
 import time
 
-# --mesh on a host without enough devices (CPU smoke runs): force the host
-# platform device count BEFORE the first jax initialization -- mirrors
-# dryrun.py, but only when the operator did not set XLA_FLAGS themselves.
+# --mesh on a CPU-pinned run (JAX_PLATFORMS=cpu): force the host platform
+# device count BEFORE the first jax initialization -- mirrors dryrun.py, but
+# only when the operator did not set XLA_FLAGS themselves.
 from repro.launch.mesh import parse_mesh_spec, preinit_mesh_flag
 
 preinit_mesh_flag(sys.argv)
@@ -52,9 +52,9 @@ from repro import configs
 from repro.configs.base import QuantConfig
 from repro.models import (
     build_model,
+    init_and_quantize,
     load_servable,
     make_smoke_batch,
-    quantize_and_plan,
     save_servable,
 )
 from repro.serving import (
@@ -70,7 +70,7 @@ from repro.serving import (
 
 
 def tree_mb(tree) -> float:
-    return sum(np.asarray(l).nbytes for l in jax.tree.leaves(tree)) / 1e6
+    return sum(l.nbytes for l in jax.tree.leaves(tree)) / 1e6
 
 
 def boot_from_artifact(artifact_dir: str, mesh=None):
@@ -102,15 +102,16 @@ def boot_quantize(args, mesh=None):
                      fmt=getattr(args, "fmt", None))
     cfg = (configs.get_smoke if args.smoke else configs.get_config)(args.arch, qc)
     api = build_model(cfg)
-    params = api.init(jax.random.PRNGKey(0))
     calib = None
     if args.calibrate:
         calib = [
             make_smoke_batch(jax.random.PRNGKey(100 + i), cfg, batch=2, seq=16)
             for i in range(args.calibrate)
         ]
-    qparams, plan, api = quantize_and_plan(api, params, calib_batches=calib)
-    fp_mb, q_mb = tree_mb(params), tree_mb(qparams)
+    qparams, plan, api, fp_bytes = init_and_quantize(
+        api, jax.random.PRNGKey(0), calib_batches=calib
+    )
+    fp_mb, q_mb = fp_bytes / 1e6, tree_mb(qparams)
     print(f"arch={cfg.name} weights {fp_mb:.1f} MB -> {q_mb:.1f} MB "
           f"({fp_mb / q_mb:.1f}x)  plan: {len(plan.site_paths)} sites, "
           f"{len(plan.act_exponents)} calibrated")
@@ -153,11 +154,6 @@ def main():
                          "kernel -- one pass over the packed cache per "
                          "chunk, which is what moves TTFT; independent "
                          "of --flash-decode")
-    ap.add_argument("--compile-cache", default=None, metavar="DIR",
-                    help="persistent jax compilation cache: boot-time "
-                         "decode/prefill compiles become disk reads on "
-                         "the second boot (JAX_COMPILATION_CACHE_DIR is "
-                         "honored when the flag is absent)")
     ap.add_argument("--slots", type=int, default=4)
     ap.add_argument("--max-len", type=int, default=64)
     ap.add_argument("--requests", type=int, default=8)
@@ -184,11 +180,12 @@ def main():
                     help="serve sharded, e.g. 'dp=2,ep=2' (dp->data, "
                          "ep/tp->model); cold starts assemble per-host "
                          "shard files straight onto their devices")
-    ap.add_argument("--backend", default="xla",
+    ap.add_argument("--backend", default=QuantConfig.backend,
                     choices=["xla", "xla_int8", "pallas", "pallas_ep",
                              "ref", "auto"],
                     help="qmatmul backend the compiled plan carries "
-                         "(pallas_ep routes MoE expert sites through the "
+                         "(auto: the Pallas integer pipeline on a TPU; "
+                         "pallas_ep routes MoE expert sites through the "
                          "shard_map fused path under --mesh)")
     # fault tolerance: deadlines, load shedding, overload SLOs, chaos
     ap.add_argument("--deadline-ms", type=float, default=None, metavar="MS",
@@ -214,10 +211,8 @@ def main():
 
     from repro.launch.mesh import enable_compile_cache
 
-    cache_dir = enable_compile_cache(args.compile_cache)
-    if cache_dir:
-        print(f"compile cache: {cache_dir} (persistent; cold-start "
-              "compiles replay from disk)")
+    print(f"compile cache: {enable_compile_cache()} (persistent; "
+          "cold-start compiles replay from disk)")
     mesh = parse_mesh_spec(args.mesh) if args.mesh else None
     if args.artifact:
         api, qparams, plan = boot_from_artifact(args.artifact, mesh=mesh)
@@ -242,9 +237,15 @@ def main():
 
     # the startup banner always states both flash knobs: "on for decode,
     # off for prefill" is a valid -- and previously invisible -- state
+    # the flash kernel needs a whole cache per device; a sharded cache
+    # attends through XLA (parallel/sharding.kernels_routable)
+    flash_off = (mesh is not None and mesh.size > 1
+                 and (api.cfg.flash_decode or api.cfg.flash_prefill))
     print(f"kv cache: fmt={kv_fmt_lib.resolve_kv_fmt(api.cfg)} "
           f"flash_decode={api.cfg.flash_decode} "
-          f"flash_prefill={api.cfg.flash_prefill}")
+          f"flash_prefill={api.cfg.flash_prefill}"
+          + (" (not under this mesh: XLA attends over the sharded cache)"
+             if flash_off else ""))
     cfg = api.cfg
 
     faults = FaultInjector.from_spec(args.chaos) if args.chaos else None
@@ -312,6 +313,14 @@ def main():
                   f"(n={p['n']})")
     for r in done[:4]:
         print(f"  req {r.uid}: {r.output}")
+    # shed, expired or failed requests are the point of a chaos or
+    # admission-limited run; anywhere else a request that did not finish
+    # is a failed run
+    lost = (len([r for r in done + not_admitted if r.status != "finished"])
+            + len(left["in_flight"]) + len(left["queued"]))
+    limits = (args.deadline_ms, args.max_queue, args.ttft_slo_ms)
+    if lost and args.chaos is None and all(v is None for v in limits):
+        sys.exit(f"{lost} of {args.requests} requests did not finish")
 
 
 if __name__ == "__main__":

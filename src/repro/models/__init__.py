@@ -3,6 +3,7 @@ from repro.models.layers import QuantCtx
 from repro.models.model_zoo import (
     ModelApi,
     build_model,
+    init_and_quantize,
     input_specs,
     load_servable,
     make_ctx,

@@ -32,6 +32,7 @@ import jax.numpy as jnp
 
 from repro.models import kv_cache, layers
 from repro.models.layers import QuantCtx, dense
+from repro.parallel import sharding as _sh
 
 NEG_INF = -1e30
 
@@ -163,20 +164,6 @@ def _attend_chunked(q, k, v, q_pos, causal, window, chunk: int):
     return acc / denom
 
 
-def _flash_routable() -> bool:
-    """The flash kernels assume every packed cache leaf is whole per device.
-
-    Under a multi-device activation mesh the cache is kv-head-sharded --
-    or sequence-sharded when ``KV_SEQ_SHARD`` kicks in (GQA head counts
-    that do not divide the TP width) -- and a pallas_call is not
-    partitionable over either axis, so routing falls back to the XLA
-    oracle, which shards correctly.  Single-device (or no) mesh: route."""
-    from repro.parallel import sharding as _sh
-
-    mesh = _sh._ACT_MESH[0]
-    return mesh is None or mesh.size == 1
-
-
 def _win_arg(window) -> jax.Array:
     return jnp.asarray(
         2**30 if window is None else window, jnp.int32
@@ -295,7 +282,7 @@ def attention(
     if decode:
         # flash routing: S == 1 under cfg.flash_decode, S > 1 cache-attends
         # (chunked prefill) under cfg.flash_prefill -- independent knobs.
-        # Both require a whole-per-device cache (_flash_routable); S > 1
+        # Both require a whole-per-device cache (kernels_routable); S > 1
         # additionally requires a causal layer (the kernel's masking
         # contract), which every self-attention prefill chunk is.
         flash = (
@@ -303,7 +290,7 @@ def attention(
             if x.shape[1] == 1
             else getattr(cfg, "flash_prefill", False) and causal
         )
-        if flash and _flash_routable():
+        if flash and _sh.kernels_routable():
             out = _flash_cache_path(
                 q, new_cache, fmt, q_pos, valid, window, cfg
             )
@@ -334,15 +321,13 @@ def attention(
         and causal
         and kv_src is None
         and getattr(cfg, "flash_prefill", False)
-        and _flash_routable()
+        and _sh.kernels_routable()
     ):
         out = _flash_self_path(q, k, v, window, cfg).astype(x.dtype)
         return dense(p["wo"], out, f"{path}/wo", ctx), new_cache
 
     # training / prefill: repeat KV to full heads so the head axis shards
     # over 'model' even when n_kv_heads does not divide the TP width.
-    from repro.parallel import sharding as _sh
-
     if g > 1:
         k = jnp.repeat(k, g, axis=2)
         v = jnp.repeat(v, g, axis=2)

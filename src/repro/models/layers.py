@@ -56,7 +56,7 @@ def dense(
             bias=p.get("b"), act=act, backend=ctx.backend,
             act_bits=prec.act_bits if prec else 8,
             act_exponent=ctx.act_exponent(path),
-            fused=prec.fused if prec else True,
+            fused=prec.fused if prec else True, site=path,
         )
         return y.astype(x.dtype)
     if ctx.mode == "qat" and (ctx.plan is not None or ctx.policy is not None):

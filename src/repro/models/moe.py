@@ -28,6 +28,7 @@ from repro.quant.api import observe_site
 from repro.quant.backends import (
     ep_divisible,
     expert_ffn_ep,
+    expert_ffn_local,
     qmatmul,
     resolve_backend,
 )
@@ -92,10 +93,10 @@ def _expert_matmul(w, x, path: str, ctx: QuantCtx, prec=None, buf_axes=None) -> 
         # stop the partitioner replicating the f32 act-quant tensors inside
         # the chunk loop; instead it un-hoisted the weight dequantization
         # (8.5x flops, +12 GiB temps on grok x prefill_32k).  The vmapped
-        # qmatmul below lets XLA hoist.  Under the "pallas_ep" backend with a
-        # mesh installed, expert sites bypass this function entirely through
-        # the shard_map EP path (_expert_ffn below), which decodes only the
-        # local expert slices -- no replicated f32 act-quant gathers.
+        # qmatmul below lets XLA hoist.  The kernel backends bypass this
+        # function (_expert_ffn below): on one device through the fused
+        # per-expert sites, under a mesh through the shard_map EP path,
+        # which decodes only the local expert slices.
         site_prec = ctx.resolve(path)
         return jax.vmap(
             lambda qt, xe: qmatmul(
@@ -146,31 +147,38 @@ def _expert_ffn(experts, xb: jax.Array, path: str, ctx: QuantCtx, buf_axes):
     PTQ under the "pallas_ep" backend with an installed mesh runs the whole
     FFN as ONE shard_map over the expert ('model') axis: dispatch/combine
     all-to-alls inside the body, fused qdense on the local expert slices
-    (gate silu in the kernel epilogue).  Every other mode composes the three
-    ``_expert_matmul`` sites exactly as before, so the EP path has a
-    bit-identical single-device oracle."""
+    (gate silu in the kernel epilogue).  PTQ on a kernel backend with no
+    mesh runs the same body on one device, without the all-to-alls.  Every
+    other mode composes the three ``_expert_matmul`` sites, so both have a
+    bit-identical oracle (the ``ref`` backend)."""
     mesh = sharding._ACT_MESH[0]
-    if _use_ep(experts, xb.shape[0], xb.shape[1], ctx):
-        # (no observer handling: calibration always runs on float params, so
-        # the QTensor guard above keeps the observing pass on the oracle path)
-        def site_kw(name):
-            site = f"{path}/experts/{name}"
-            prec = ctx.resolve(site)
-            return {
-                "act_bits": prec.act_bits if prec else 8,
-                "act_exponent": ctx.act_exponent(site),
-                "fused": prec.fused if prec else True,
-            }
+    # (no observer handling below: calibration always runs on float params,
+    # so the QTensor guard keeps the observing pass on the oracle path)
+    def site_kw(name):
+        site = f"{path}/experts/{name}"
+        prec = ctx.resolve(site)
+        return {
+            "act_bits": prec.act_bits if prec else 8,
+            "act_exponent": ctx.act_exponent(site),
+            "fused": prec.fused if prec else True,
+        }
 
+    weights = lambda: {n: experts[n]["w"] for n in ("gate", "up", "down")}
+    sites = lambda: {n: site_kw(n) for n in ("gate", "up", "down")}
+    if _use_ep(experts, xb.shape[0], xb.shape[1], ctx):
         return expert_ffn_ep(
-            {name: experts[name]["w"] for name in ("gate", "up", "down")},
-            xb,
-            mesh=mesh,
-            ep_axis="model",
+            weights(), xb, mesh=mesh, ep_axis="model",
             cap_axes=_ep_cap_axes(mesh, xb.shape[1]),
-            backend=ctx.backend,
-            site_kwargs={n: site_kw(n) for n in ("gate", "up", "down")},
+            backend=ctx.backend, site_kwargs=sites(),
         )
+    if (
+        isinstance(experts["gate"]["w"], QTensor)
+        and resolve_backend(ctx.backend) in ("pallas", "pallas_ep")
+        and sharding.kernels_routable()
+    ):
+        # one device: the EP body's fused sites, without the all-to-alls
+        return expert_ffn_local(weights(), xb, backend=ctx.backend,
+                                site_kwargs=sites())
     em = lambda name, val: _expert_matmul(
         experts[name]["w"], val, f"{path}/experts/{name}", ctx,
         prec=experts[name].get("_prec"), buf_axes=buf_axes,

@@ -20,10 +20,6 @@ from repro.models.layers import QuantCtx
 from repro.parallel import sharding
 
 
-def _stack(trees):
-    return jax.tree.map(lambda *xs: jnp.stack(xs), *trees)
-
-
 def window_schedule(cfg, seq_len: int) -> Optional[jax.Array]:
     """Per-layer attention window; None when the arch has no local layers."""
     if not cfg.sliding_window:
@@ -56,7 +52,9 @@ def init_lm(key, cfg) -> Dict[str, Any]:
     block_keys = jax.random.split(kb, cfg.n_layers)
     params = {
         "embed": layers.init_embedding(ke, cfg.padded_vocab, cfg.d_model, dtype),
-        "blocks": _stack([init_block(k, cfg, dtype) for k in block_keys]),
+        # vmapped over the layer keys: the same values as initializing each
+        # block and stacking, in a program that does not grow with depth
+        "blocks": jax.vmap(lambda k: init_block(k, cfg, dtype))(block_keys),
         "final_norm": layers.init_rmsnorm(cfg.d_model, dtype),
         "lm_head": layers.init_dense_layer(kh, cfg.d_model, cfg.padded_vocab, False, dtype),
     }

@@ -38,6 +38,7 @@ newly registered format (nf4, mx) shards correctly with no rule changes.
 """
 from __future__ import annotations
 
+import contextlib
 import re
 from typing import Any, Optional, Tuple
 
@@ -277,6 +278,29 @@ KV_SEQ_SHARD: list = [True]
 
 def set_activation_mesh(mesh: Optional[Mesh]) -> None:
     _ACT_MESH[0] = mesh
+
+
+def kernels_routable() -> bool:
+    """May a Pallas kernel run here on whole per-device operands?
+
+    XLA cannot partition a Mosaic kernel, so under a multi-device
+    activation mesh a pallas_call runs only inside a shard_map body (traced
+    under ``manual_region``, which lifts the mesh); everywhere else callers
+    take their XLA path, which shards correctly."""
+    mesh = _ACT_MESH[0]
+    return mesh is None or mesh.size == 1
+
+
+@contextlib.contextmanager
+def manual_region():
+    """Trace a shard_map body: its operands are per-device blocks, so the
+    code inside it sees no activation mesh."""
+    prev = _ACT_MESH[0]
+    _ACT_MESH[0] = None
+    try:
+        yield
+    finally:
+        _ACT_MESH[0] = prev
 
 
 def constrain(x, logical_axes) -> Any:

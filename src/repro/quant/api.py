@@ -111,6 +111,42 @@ def _quantizable(prec, kdim: int) -> bool:
     )
 
 
+def _quantize_leaf(w, scales, *, bits, group_size, filter_size, refit_scale,
+                   fmt):
+    """One projection leaf -> QTensor; stacked leading axes (layers,
+    experts) quantize one matrix at a time.  ``lax.map`` bounds the working
+    set to a single matrix's f32 temporaries, where vmap would hold them
+    for the whole stack at once: several GB per leaf at published widths,
+    more than a 16 GB chip holds beside the float model."""
+    kw = dict(bits=bits, group_size=group_size, filter_size=filter_size,
+              refit_scale=refit_scale, fmt=fmt)
+    if w.ndim > 2:
+        if scales is None:
+            return jax.lax.map(lambda m: _quantize_leaf(m, None, **kw), w)
+        return jax.lax.map(lambda a: _quantize_leaf(*a, **kw), (w, scales))
+    return quantize_weights(
+        w.astype(jnp.float32), bits, group_size, filter_size, refit_scale,
+        fmt=fmt, scales=None if scales is None else scales.astype(jnp.float32),
+    )
+
+
+_quantize_stack = jax.jit(
+    _quantize_leaf,
+    static_argnames=("bits", "group_size", "filter_size", "refit_scale", "fmt"),
+)
+
+
+@jax.jit
+def _snap_table(table):
+    """Embedding table snapped to the per-row 8-bit DFP grid, in its own
+    storage dtype (one fused pass: no f32 copy of the table survives)."""
+    from repro.core import calibration
+
+    return calibration.fake_quantize_act(
+        table.astype(jnp.float32), 8, per_row=True
+    ).astype(table.dtype)
+
+
 def quantize_params(params, plan: QuantPlan):
     """Walk the param tree; replace projection 'w' leaves with QTensors.
 
@@ -126,25 +162,14 @@ def quantize_params(params, plan: QuantPlan):
     artifact is never re-fit from the master weights.  State leaves are
     consumed here -- the output tree holds only servable parameters.
     """
-    from repro.core import calibration
     from repro.quant.state import STATE_KEYS
 
     def quant_w(w, prec, scales=None):
-        def q2(m, sc=None):
-            return quantize_weights(
-                m, prec.w_bits, prec.group_size, prec.filter_size,
-                prec.refit_scale, fmt=prec.fmt, scales=sc,
-            )
-
-        if scales is None:
-            fn = lambda m: q2(m)
-            for _ in range(w.ndim - 2):
-                fn = jax.vmap(fn)
-            return fn(w.astype(jnp.float32))
-        fn = q2
-        for _ in range(w.ndim - 2):
-            fn = jax.vmap(fn)
-        return fn(w.astype(jnp.float32), scales.astype(jnp.float32))
+        return _quantize_stack(
+            w, scales, bits=prec.w_bits, group_size=prec.group_size,
+            filter_size=prec.filter_size, refit_scale=prec.refit_scale,
+            fmt=prec.fmt,
+        )
 
     def walk(node, path):
         if isinstance(node, dict):
@@ -168,9 +193,7 @@ def quantize_params(params, plan: QuantPlan):
                 elif key in STATE_KEYS:
                     continue  # consumed above; not a servable parameter
                 elif key == "table" and hasattr(val, "ndim"):
-                    out[key] = calibration.fake_quantize_act(
-                        val.astype(jnp.float32), 8, per_row=True
-                    ).astype(val.dtype)
+                    out[key] = _snap_table(val)
                 else:
                     out[key] = walk(val, sub)
             return out

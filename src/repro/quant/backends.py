@@ -23,6 +23,10 @@ then dispatches to a registered backend strategy:
                    ('model') mesh axis, with the dispatch/combine
                    all-to-alls inside the body, so each device decodes and
                    activation-quantizes only its local expert slices.
+
+XLA cannot partition a Mosaic kernel, so under a multi-device activation
+mesh the kernel backends run every dense site as one ``shard_map`` over the
+weight's serve layout (``_qdense_sharded``); ``qmatmul`` there raises.
   * ``auto``     : resolves to pallas on TPU, xla otherwise.
 
 Every strategy receives the already-quantized activations ``(xq, xe)`` plus
@@ -40,7 +44,9 @@ backend stays the bit-exact oracle for both.
 """
 from __future__ import annotations
 
+import dataclasses
 import functools
+import math
 from typing import Any, Callable, Dict, Optional, Tuple
 
 import jax
@@ -48,9 +54,11 @@ import jax.numpy as jnp
 
 from repro.core import dfp
 from repro.core.quantizer import QTensor
-from repro.kernels._common import activation_fn, m_bucket, pick_block
+from repro.kernels._common import activation_fn, interpret_mode, m_bucket, pick_block
 from repro.kernels.quantize import quantize_rows
 from repro.kernels.ref import qmatmul_ref, quantize_rows_ref
+from repro.parallel import sharding as rules
+from repro.parallel.sharding import kernels_routable, manual_region
 
 # fn(xq int8 (M, K), xe int32 ((M,1) or scalar), qt, *, block_m, block_n,
 #    block_k) -> f32 (M, N), exponents applied.
@@ -78,15 +86,24 @@ def backend_names() -> Tuple[str, ...]:
     return tuple(sorted(_BACKENDS))
 
 
-def _on_tpu() -> bool:
-    return jax.default_backend() == "tpu"
+# strategies that launch Pallas kernels outside any shard_map
+_KERNEL_BACKENDS = ("pallas", "pallas_ep")
 
 
 def resolve_backend(name: str) -> str:
-    """'auto' -> pallas on TPU, xla elsewhere; concrete names pass through."""
+    """'auto' -> pallas on TPU, xla elsewhere; concrete names pass through.
+
+    Off the TPU, Pallas would only run interpreted, so 'auto' takes the
+    XLA float path there; 'pallas' still forces the interpreted kernels."""
     if name == "auto":
-        return "pallas" if _on_tpu() else "xla"
+        return "xla" if interpret_mode() else "pallas"
     return name
+
+
+def _needs_shard_map(name: str) -> bool:
+    """Does a site on resolved backend ``name`` have to run its kernels
+    inside a shard_map (a kernel backend under a multi-device mesh)?"""
+    return name in _KERNEL_BACKENDS and not kernels_routable()
 
 
 # ---------------------------------------------------------------------------
@@ -110,16 +127,18 @@ def quantize_activations(
       * pallas on TPU        (use_pallas defaults to True on TPU),
       * pallas interpret mode (use_pallas=True off-TPU; exact but slow --
         used by tests to validate the kernel semantics),
-      * the jnp reference    (use_pallas=False; default off-TPU).
+      * the jnp reference    (use_pallas=False; default off-TPU, and for
+        the XLA backends under a multi-device mesh, which XLA partitions).
     """
     if exponent is not None:
         e = jnp.asarray(exponent, jnp.int32)
         return dfp.quantize(x, e, bits), e
+    interpret = interpret_mode()
     if use_pallas is None:
-        use_pallas = _on_tpu()
+        use_pallas = not interpret and kernels_routable()
     if not use_pallas:
         return quantize_rows_ref(x, bits)
-    return quantize_rows(x, bits=bits, interpret=not _on_tpu())
+    return quantize_rows(x, bits=bits, interpret=interpret)
 
 
 # ---------------------------------------------------------------------------
@@ -190,7 +209,7 @@ def _pallas_backend(xq, xe, qt: QTensor, *, block_m=128, block_n=128, block_k=51
     out = kernel(
         xq, qt.packed, qt.scale_m,
         group=qt.group_size, block_m=pick_block(xq.shape[0], block_m),
-        block_n=block_n, block_k=block_k, interpret=not _on_tpu(),
+        block_n=block_n, block_k=block_k, interpret=interpret_mode(),
     )
     out = out[:m]
     return out * dfp.exp2i(qt.scale_e + xe)
@@ -246,7 +265,7 @@ def _pallas_fused(
         group=qt.group_size, bias=bias, act=act, act_bits=act_bits,
         act_exponent=None if act_exponent is None else int(act_exponent),
         block_m=pick_block(x.shape[0], block_m), block_n=block_n,
-        block_k=block_k, interpret=not _on_tpu(),
+        block_k=block_k, interpret=interpret_mode(),
     )
     return out[:m]
 
@@ -277,7 +296,16 @@ def qmatmul(
     """
     lead = x.shape[:-1]
     xm = x.reshape(-1, x.shape[-1])
-    fn = get_backend(resolve_backend(backend))
+    name = resolve_backend(backend)
+    if _needs_shard_map(name):
+        raise ValueError(
+            f"qmatmul on the {name!r} backend under a multi-device mesh: "
+            "XLA cannot partition its Pallas kernels.  Route the site "
+            "through qdense(..., site=path) (a shard_map over the weight "
+            "layout), serve MoE experts with 'pallas_ep' on an expert count "
+            "the EP axis divides, or pick 'xla_int8'."
+        )
+    fn = get_backend(name)
     xq, xe = quantize_activations(xm, act_bits, exponent=act_exponent)
     out = fn(xq, xe, qt, block_m=block_m, block_n=block_n, block_k=block_k)
     return out.reshape(*lead, qt.n)
@@ -314,6 +342,26 @@ def _qdense_stack(x, qt: QTensor, **kw):
     """qdense vmapped over a stacked (E_local, ...) expert axis: each local
     expert's site is one fused pallas_call over its local buffer slice."""
     return jax.vmap(lambda xe, qe: qdense(xe, qe, **kw), in_axes=(0, 0))(x, qt)
+
+
+def expert_ffn_local(
+    experts: Any,  # {"gate": QTensor (E, d, ff), "up": ..., "down": (E, ff, d)}
+    x: jax.Array,  # (E, C, d) capacity buffer of the experts held here
+    *,
+    backend: str,
+    site_kwargs: Optional[Dict[str, Dict[str, Any]]] = None,
+) -> jax.Array:
+    """The expert FFN over experts held whole on this device: the three
+    projections as per-expert ``qdense`` sites, gate's silu in its kernel
+    epilogue -- the body of ``expert_ffn_ep`` and the one-device path."""
+    sites = site_kwargs or {}
+    kw = lambda name: dict(backend=backend, **sites.get(name, {}))
+    h = _qdense_stack(x, experts["gate"], act="silu", **kw("gate"))
+    # h stays f32 into the down projection, exactly like the unfused
+    # oracle composition -- casting to the model dtype here would break
+    # bit parity with the single-device path on bf16 models
+    h = h * _qdense_stack(x, experts["up"], **kw("up"))
+    return _qdense_stack(h, experts["down"], **kw("down"))
 
 
 def ep_divisible(e: int, c: int, mesh, ep_axis: str = "model",
@@ -360,11 +408,7 @@ def expert_ffn_ep(
     plan) -- per-site so the EP path quantizes each projection exactly like
     the single-device oracle composition does.
     """
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
-
-    sites = site_kwargs or {}
-    kw = lambda name: dict(backend=backend, **sites.get(name, {}))
 
     def body(gq, uq, dq, xs):
         # xs: (E, C_local, d) -- this device's capacity shard of every expert.
@@ -373,12 +417,8 @@ def expert_ffn_ep(
         # token routed to them.
         xl = jax.lax.all_to_all(xs, ep_axis, split_axis=0, concat_axis=1,
                                 tiled=True)
-        h = _qdense_stack(xl, gq, act="silu", **kw("gate"))
-        # h stays f32 into the down projection, exactly like the unfused
-        # oracle composition -- casting to the model dtype here would break
-        # bit parity with the single-device path on bf16 models
-        h = h * _qdense_stack(xl, uq, **kw("up"))
-        y = _qdense_stack(h, dq, **kw("down"))
+        y = expert_ffn_local({"gate": gq, "up": uq, "down": dq}, xl,
+                             backend=backend, site_kwargs=site_kwargs)
         # Combine all-to-all: back to capacity sharding for the gather.
         # Cast to the model dtype FIRST -- astype is elementwise, so moving
         # it across the pure data movement is bit-identical, and the combine
@@ -391,13 +431,78 @@ def expert_ffn_ep(
     cap = tuple(cap_axes) + (ep_axis,)
     xspec = P(None, cap, None)
     wspec = P(ep_axis)  # leading expert axis of every QTensor field
-    fn = shard_map(
-        body, mesh,
+    fn = jax.shard_map(
+        body, mesh=mesh,
         in_specs=(wspec, wspec, wspec, xspec),
         out_specs=xspec,
-        check_rep=False,
+        check_vma=False,
     )
-    return fn(experts["gate"], experts["up"], experts["down"], x)
+    with manual_region():  # the body's fused kernels see per-device blocks
+        return fn(experts["gate"], experts["up"], experts["down"], x)
+
+
+def _qdense_sharded(
+    xm, qt: QTensor, *, mesh, site: str, bias, act, backend: str,
+    act_bits: int, act_exponent, fused: bool, block_m: int, block_n: int,
+    block_k: int,
+) -> jax.Array:
+    """One dense site under a multi-device mesh, as ONE shard_map over the
+    weight's serve layout (``parallel/sharding.qtensor_spec`` of ``site``).
+
+    Rows shard over the data axes and every device holds whole rows, so
+    each row's dynamic DFP exponent sees its full K, as on one device:
+      * N over 'model' (column-parallel) or replicated: each device runs
+        the site's kernels on its (K, N/tp) block -- the one-device
+        computation, column for column;
+      * K over 'model' (row-parallel: wo, down): each device quantizes its
+        rows with the ``quantize_rows`` kernel, runs the unfused kernel on
+        its K slice (exponents applied), and the f32 partials are summed
+        over the axis; bias and activation follow the sum.
+    """
+    from jax.sharding import PartitionSpec as P
+
+    spec = rules.qtensor_spec(site, qt, mesh, "serve")
+    k_ax, n_ax = spec[-2], spec[-1]
+    k_div = mesh.shape[k_ax] if k_ax else 1
+    n_div = mesh.shape[n_ax] if n_ax else 1
+    rows = rules.batch_axes(mesh)
+    if rows is not None and xm.shape[0] % math.prod(mesh.shape[a] for a in rows):
+        rows = None
+    wspec = P(k_ax, n_ax)
+    qspec = dataclasses.replace(qt, packed=wspec, scale_m=wspec, scale_e=P())
+    local = lambda q: dataclasses.replace(q, shape=(qt.k // k_div, qt.n // n_div))
+    kw = dict(backend=backend, act_bits=act_bits, act_exponent=act_exponent,
+              block_m=block_m, block_n=block_n, block_k=block_k)
+
+    if k_ax is None:
+        def body(x, q, *b):
+            return qdense(x, local(q), bias=b[0] if b else None, act=act,
+                          fused=fused, **kw)
+        out_spec = P(rows, n_ax)
+    else:
+        def body(x, q, *b):
+            q = local(q)
+            xq, xe = quantize_activations(x, act_bits, exponent=act_exponent)
+            xq = jax.lax.dynamic_slice_in_dim(
+                xq, jax.lax.axis_index(k_ax) * q.k, q.k, axis=1
+            )
+            out = get_backend(backend)(
+                xq, xe, q, block_m=block_m, block_n=block_n, block_k=block_k
+            )
+            out = jax.lax.psum(out, k_ax)
+            if b:
+                out = out + b[0].astype(jnp.float32)
+            return apply_act(out, act)
+        out_spec = P(rows, None)
+
+    args, in_specs = [xm, qt], [P(rows, None), qspec]
+    if bias is not None:
+        args.append(bias)
+        in_specs.append(P(n_ax))
+    fn = jax.shard_map(body, mesh=mesh, in_specs=tuple(in_specs),
+                       out_specs=out_spec, check_vma=False)
+    with manual_region():  # the body's kernels see per-device blocks
+        return fn(*args)
 
 
 def qdense(
@@ -410,12 +515,17 @@ def qdense(
     act_bits: int = 8,
     act_exponent=None,
     fused: bool = True,
+    site: Optional[str] = None,
     block_m: int = 128,
     block_n: int = 128,
     block_k: int = 512,
 ) -> jax.Array:
     """One quantized dense site: x [..., K] -> f32 [..., N] with the scale
     exponents, ``bias`` and ``act`` ("silu"/"gelu"/"relu") already applied.
+
+    ``site`` is the weight's param path; a kernel backend under a
+    multi-device activation mesh needs it to shard the site over the
+    weight's serve layout (``_qdense_sharded``).
 
     On a backend with a registered fused strategy (and ``fused=True``, the
     per-site plan knob) the whole site is ONE kernel launch: activations are
@@ -429,7 +539,18 @@ def qdense(
     lead = x.shape[:-1]
     xm = x.reshape(-1, x.shape[-1])
     name = resolve_backend(backend)
-    if fused and _fused_available(name, qt):
+    if _needs_shard_map(name):
+        if site is None:
+            raise ValueError(
+                f"qdense on the {name!r} backend under a multi-device mesh "
+                "needs site= (the weight's param path) to find its layout"
+            )
+        out = _qdense_sharded(
+            xm, qt, mesh=rules._ACT_MESH[0], site=site, bias=bias, act=act,
+            backend=name, act_bits=act_bits, act_exponent=act_exponent,
+            fused=fused, block_m=block_m, block_n=block_n, block_k=block_k,
+        )
+    elif fused and _fused_available(name, qt):
         out = _FUSED_BACKENDS[name](
             xm, qt, bias=bias, act=act, act_bits=act_bits,
             act_exponent=act_exponent, block_m=block_m, block_n=block_n,

@@ -623,6 +623,17 @@ class _EngineBase:
         raise NotImplementedError
 
     # -- introspection ------------------------------------------------------
+    def compiled_programs(self) -> Dict[str, Any]:
+        """The engine's own jitted programs, compiled at the shapes it
+        dispatches (the decode tick over every slot), to inspect what runs
+        on the device."""
+        tokens, pos = self._device_operands()
+        with self._dispatch():
+            return {"decode": self._decode_step.lower(
+                self.params, tokens, pos, self.cache, self.key,
+                jnp.int32(-1), jnp.float32(0.0),
+            ).compile()}
+
     def stats(self) -> Dict[str, Any]:
         return {
             "active": sum(r is not None for r in self.slot_req),
@@ -922,6 +933,18 @@ class StagedEngine(_EngineBase):
         return completed
 
     # -- introspection ------------------------------------------------------
+    def compiled_programs(self) -> Dict[str, Any]:
+        """Adds the prefill step at one chunk of the configured size."""
+        out = super().compiled_programs()
+        if self._prefill_step is not None:
+            toks = jnp.zeros((1, self.sched.prefill_chunk), jnp.int32)
+            with self._dispatch():
+                out["prefill"] = self._prefill_step.lower(
+                    self.params, toks, jnp.int32(0),
+                    self.api.init_cache(1, self.max_len),
+                ).compile()
+        return out
+
     def stats(self) -> Dict[str, Any]:
         out = super().stats()
         pf = self._pf

@@ -274,8 +274,12 @@ def _read_retry(read: Callable[[str], Any], fpath: str) -> Any:
             delay *= 2
 
 
-def _np_load(fpath: str) -> np.ndarray:
-    return _read_retry(np.load, fpath)
+def _np_load(fpath: str, dtype: str) -> np.ndarray:
+    """Load one .npy payload as the manifest's ``dtype``.  ``np.save``
+    stores dtypes numpy cannot name (bfloat16) as raw void bytes of the
+    same width; they are viewed back here."""
+    arr = _read_retry(np.load, fpath)
+    return arr.view(np.dtype(dtype)) if arr.dtype.kind == "V" else arr
 
 
 def _sha256_once(fpath: str) -> str:
@@ -547,11 +551,11 @@ def _load_payload(d: str, meta: Dict[str, Any]) -> np.ndarray:
     """Host-side load of one payload; sharded payloads concatenate into a
     single host array (the mesh-free / template-``restore`` path)."""
     if "shards" not in meta:
-        return _np_load(os.path.join(d, meta["file"]))
+        return _np_load(os.path.join(d, meta["file"]), meta["dtype"])
     out = np.empty(tuple(meta["shape"]), np.dtype(meta["dtype"]))
     for s in meta["shards"]:
         sl = tuple(slice(a, b) for a, b in s["index"])
-        out[sl] = _np_load(os.path.join(d, s["file"]))
+        out[sl] = _np_load(os.path.join(d, s["file"]), meta["dtype"])
     return out
 
 
@@ -579,7 +583,9 @@ def _load_payload_on_mesh(d: str, meta: Dict[str, Any], sharding) -> jax.Array:
             for dev, idx in imap.items():
                 fname = saved[_norm_index(idx, shape)]
                 if fname not in cache:
-                    cache[fname] = _np_load(os.path.join(d, fname))
+                    cache[fname] = _np_load(
+                        os.path.join(d, fname), meta["dtype"]
+                    )
                 pieces.append(jax.device_put(cache[fname], dev))
             return jax.make_array_from_single_device_arrays(
                 shape, sharding, pieces
@@ -636,7 +642,7 @@ def restore(
         meta = manifest["arrays"].get(name)
         if meta is None:
             raise KeyError(f"checkpoint missing array {name!r}")
-        arr = _np_load(os.path.join(d, meta["file"]))
+        arr = _np_load(os.path.join(d, meta["file"]), meta["dtype"])
         if list(arr.shape) != list(leaf.shape):
             raise ValueError(f"{name}: shape {arr.shape} != template {leaf.shape}")
         if shard is not None:

@@ -137,6 +137,25 @@ def test_artifact_roundtrip_block_formats(fmt, tmp_path):
     assert np.array_equal(np.asarray(l_mem), np.asarray(l_cold))
 
 
+def test_artifact_roundtrip_bf16_model(tmp_path):
+    """Published configs are bfloat16: the unquantized leaves (norm gains,
+    the snapped embedding table) must load back as bfloat16, though .npy
+    stores that dtype as raw two-byte records."""
+    cfg = dataclasses.replace(
+        configs.get_smoke(
+            "qwen3-8b",
+            QuantConfig(w_bits=2, group_size=16, mode="ptq", backend="xla"),
+        ),
+        dtype="bfloat16",
+    )
+    api = build_model(cfg)
+    qparams, plan, qapi = quantize_and_plan(api, api.init(KEY))
+    assert any(l.dtype == jnp.bfloat16 for l in jax.tree.leaves(qparams))
+    save_servable(str(tmp_path), qapi, qparams, plan)
+    _, loaded, _ = load_servable(str(tmp_path))
+    _assert_trees_bit_exact(qparams, loaded)
+
+
 def test_legacy_empty_fmt_manifest_resolves_by_bits(tmp_path):
     """Pre-fix artifacts stamped fmt="" (bits-resolved QTensors) must keep
     loading and resolving through the bits default -- which registration

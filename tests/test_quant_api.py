@@ -267,7 +267,7 @@ def test_quantize_activations_pallas_interpret_matches_oracle():
 
 def test_quantize_activations_dispatch_three_way(monkeypatch):
     """pallas-on-tpu / pallas-interpret / ref are each reachable and chosen
-    by (use_pallas, on_tpu) exactly."""
+    by (use_pallas, interpret_mode) exactly."""
     seen = {}
 
     def fake_quantize_rows(x, *, bits=8, interpret=False, **kw):
@@ -277,11 +277,11 @@ def test_quantize_activations_dispatch_three_way(monkeypatch):
     monkeypatch.setattr(backends_mod, "quantize_rows", fake_quantize_rows)
     x = jnp.ones((4, 16))
 
-    monkeypatch.setattr(backends_mod, "_on_tpu", lambda: True)
+    monkeypatch.setattr(backends_mod, "interpret_mode", lambda: False)
     quantize_activations(x)  # default on TPU -> pallas, compiled
     assert seen.pop("interpret") is False
 
-    monkeypatch.setattr(backends_mod, "_on_tpu", lambda: False)
+    monkeypatch.setattr(backends_mod, "interpret_mode", lambda: True)
     quantize_activations(x, use_pallas=True)  # forced pallas off-TPU
     assert seen.pop("interpret") is True
 

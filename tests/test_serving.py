@@ -228,3 +228,29 @@ def test_int8_kv_cache_greedy_parity():
         t1 = jnp.argmax(l_ref[:, -1:], -1).astype(jnp.int32)
         t2 = jnp.argmax(l_q[:, -1:], -1).astype(jnp.int32)
         assert int(t1[0, 0]) == int(t2[0, 0])
+
+
+@pytest.mark.parametrize(
+    "limits,fails",
+    [([], True), (["--deadline-ms", "60000"], False)],
+    ids=["no_limits", "admission_limit"],
+)
+def test_serve_cli_exit_code_counts_unfinished(monkeypatch, limits, fails):
+    """serve.py fails when a request does not finish -- here every prompt
+    is rejected for not fitting max_len -- unless chaos or an admission
+    limit was asked for, where lost requests are expected."""
+    import sys
+
+    from repro.launch import mesh, serve
+
+    # keep this process's jax config as it was: no persistent cache
+    monkeypatch.setattr(mesh, "enable_compile_cache", lambda: "off")
+    monkeypatch.setattr(sys, "argv", [
+        "serve", "--arch", "phi4-mini-3.8b", "--smoke", "--requests", "2",
+        "--max-len", "6", *limits,
+    ])
+    if fails:
+        with pytest.raises(SystemExit, match="2 of 2 requests did not finish"):
+            serve.main()
+    else:
+        serve.main()

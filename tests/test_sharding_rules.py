@@ -9,8 +9,8 @@ from repro.core import stats
 from repro.parallel import sharding
 from repro.roofline import analysis
 
-MESH = AbstractMesh((("data", 16), ("model", 16)))
-MESH3 = AbstractMesh((("pod", 2), ("data", 16), ("model", 16)))
+MESH = AbstractMesh((16, 16), ("data", "model"))
+MESH3 = AbstractMesh((2, 16, 16), ("pod", "data", "model"))
 
 
 class _Leaf:
