@@ -137,6 +137,7 @@ def packed_qmm_call(
     packed: jax.Array,  # per-format packed weights ((K/words_per_k, N))
     scale_m: jax.Array,  # int8 (K/group, N)
     *,
+    fmt: str,  # weight format: the kernel is named packed_qmm_<fmt>
     decode: Callable,  # (words tile, bk) -> (bk, bn) int8
     words_per_k: int,
     group: int,
@@ -173,6 +174,7 @@ def packed_qmm_call(
         # same parallel/parallel/arbitrary semantics as the fused builder
         compiler_params=None if interpret else _FUSED_COMPILER_PARAMS,
         interpret=interpret,
+        name=f"packed_qmm_{fmt}",
     )(x_q, packed, scale_m)
 
 
@@ -279,6 +281,7 @@ def fused_qmm_call(
     scale_m: jax.Array,  # int8 (K/group, N)
     scale_e: jax.Array,  # int32 scalar
     *,
+    fmt: str,  # weight format: the kernel is named fused_qmm_<fmt>
     decode: Callable,  # (words tile, bk) -> (bk, bn) int8
     words_per_k: int,  # K rows per packed row (1 for raw int8 storage)
     n: int,
@@ -327,4 +330,5 @@ def fused_qmm_call(
         scratch_shapes=[pltpu.VMEM((bm, 1), jnp.float32)],
         compiler_params=None if interpret else _FUSED_COMPILER_PARAMS,
         interpret=interpret,
+        name=f"fused_qmm_{fmt}",
     )(*args)

@@ -105,6 +105,7 @@ def flash_attention(
         ],
         compiler_params=None if interpret else _COMPILER_PARAMS,
         interpret=interpret,
+        name="flash_attention",
     )(q, k, v)
 
 

@@ -238,6 +238,7 @@ def flash_attend(
         out_shape=jax.ShapeDtypeStruct((b, kh, s * g, hd), jnp.float32),
         compiler_params=None if interpret else _COMPILER_PARAMS,
         interpret=interpret,
+        name="flash_attend",
     )(
         q_start.reshape(b).astype(jnp.int32),
         valid.reshape(b).astype(jnp.int32),

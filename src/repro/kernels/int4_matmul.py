@@ -37,7 +37,7 @@ def int4_matmul(
 ) -> jax.Array:
     return packed_qmm_call(
         x_q, packed, scale_m,
-        decode=decode4_tile, words_per_k=INT4_PER_WORD, group=group,
+        fmt="int4", decode=decode4_tile, words_per_k=INT4_PER_WORD, group=group,
         block_m=block_m, block_n=block_n, block_k=block_k,
         interpret=interpret,
     )
@@ -70,7 +70,7 @@ def int4_matmul_fused(
     + exp2/bias/activation epilogue (exponents applied in-kernel)."""
     return fused_qmm_call(
         x, packed, scale_m, scale_e,
-        decode=decode4_tile, words_per_k=INT4_PER_WORD, n=packed.shape[1],
+        fmt="int4", decode=decode4_tile, words_per_k=INT4_PER_WORD, n=packed.shape[1],
         group=group, bias=bias, act=act, act_bits=act_bits,
         act_exponent=act_exponent, block_m=block_m, block_n=block_n,
         block_k=block_k, interpret=interpret,

@@ -37,7 +37,7 @@ def int8_matmul(
 ) -> jax.Array:
     return packed_qmm_call(
         x_q, w_q, scale_m,
-        decode=_decode_raw, words_per_k=1, group=group,
+        fmt="int8", decode=_decode_raw, words_per_k=1, group=group,
         block_m=block_m, block_n=block_n, block_k=block_k,
         interpret=interpret,
     )
@@ -70,7 +70,7 @@ def int8_matmul_fused(
     + exp2/bias/activation epilogue (exponents applied in-kernel)."""
     return fused_qmm_call(
         x, w_q, scale_m, scale_e,
-        decode=_decode_raw, words_per_k=1, n=w_q.shape[1],
+        fmt="int8", decode=_decode_raw, words_per_k=1, n=w_q.shape[1],
         group=group, bias=bias, act=act, act_bits=act_bits,
         act_exponent=act_exponent, block_m=block_m, block_n=block_n,
         block_k=block_k, interpret=interpret,

@@ -40,7 +40,7 @@ def nf4_matmul(
 ) -> jax.Array:
     return packed_qmm_call(
         x_q, packed, scale_m,
-        decode=decode_nf4_tile, words_per_k=NF4_PER_WORD, group=group,
+        fmt="nf4", decode=decode_nf4_tile, words_per_k=NF4_PER_WORD, group=group,
         block_m=block_m, block_n=block_n, block_k=block_k,
         interpret=interpret,
     )
@@ -73,7 +73,7 @@ def nf4_matmul_fused(
     16-entry-LUT nf4 decode + matmul + exp2/bias/activation epilogue."""
     return fused_qmm_call(
         x, packed, scale_m, scale_e,
-        decode=decode_nf4_tile, words_per_k=NF4_PER_WORD, n=packed.shape[1],
+        fmt="nf4", decode=decode_nf4_tile, words_per_k=NF4_PER_WORD, n=packed.shape[1],
         group=group, bias=bias, act=act, act_bits=act_bits,
         act_exponent=act_exponent, block_m=block_m, block_n=block_n,
         block_k=block_k, interpret=interpret,

@@ -74,5 +74,6 @@ def quantize_rows(
         ],
         compiler_params=None if interpret else _COMPILER_PARAMS,
         interpret=interpret,
+        name="quantize_rows",
     )(x)
     return (q[:m], e[:m]) if mp != m else (q, e)

@@ -47,7 +47,7 @@ def ternary_matmul(
 ) -> jax.Array:
     return packed_qmm_call(
         x_q, packed, scale_m,
-        decode=decode2_tile, words_per_k=TERNARY_PER_WORD, group=group,
+        fmt="ternary", decode=decode2_tile, words_per_k=TERNARY_PER_WORD, group=group,
         block_m=block_m, block_n=block_n, block_k=block_k,
         interpret=interpret,
     )
@@ -80,7 +80,7 @@ def ternary_matmul_fused(
     matmul + exp2/bias/activation epilogue (exponents applied in-kernel)."""
     return fused_qmm_call(
         x, packed, scale_m, scale_e,
-        decode=decode2_tile, words_per_k=TERNARY_PER_WORD, n=packed.shape[1],
+        fmt="ternary", decode=decode2_tile, words_per_k=TERNARY_PER_WORD, n=packed.shape[1],
         group=group, bias=bias, act=act, act_bits=act_bits,
         act_exponent=act_exponent, block_m=block_m, block_n=block_n,
         block_k=block_k, interpret=interpret,

@@ -277,7 +277,8 @@ def attention(
     decode = cache is not None and (x.shape[1] == 1 or attend_cache)
     if cache is not None:
         fmt = kv_cache.resolve_kv_fmt(cfg)
-        new_cache, valid = kv_cache.write(fmt, cache, k, v, cache_index)
+        with jax.named_scope("kv_write"):
+            new_cache, valid = kv_cache.write(fmt, cache, k, v, cache_index)
 
     if decode:
         # flash routing: S == 1 under cfg.flash_decode, S > 1 cache-attends
@@ -290,25 +291,27 @@ def attention(
             if x.shape[1] == 1
             else getattr(cfg, "flash_prefill", False) and causal
         )
-        if flash and _sh.kernels_routable():
-            out = _flash_cache_path(
-                q, new_cache, fmt, q_pos, valid, window, cfg
-            )
-        else:
-            # XLA fold-the-scales oracle: grouped-KV layout over the whole
-            # cache, (..., S, T) scores, per-token scales folded in
-            ck, cv, kscale, vscale = kv_cache.attend_view(fmt, new_cache)
-            t = ck.shape[1]
-            k_pos = jnp.arange(t)
-            bias = _mask_bias(q_pos, k_pos, causal, window, valid)
-            if bias.ndim == 2:
-                bias = bias[None, None, None]  # (1,1,1,S,T)
+        with jax.named_scope("attention"):
+            if flash and _sh.kernels_routable():
+                out = _flash_cache_path(
+                    q, new_cache, fmt, q_pos, valid, window, cfg
+                )
             else:
-                bias = bias[:, None, None]  # (B,1,1,S,T)
-            qh = q.reshape(*q.shape[:2], cfg.n_kv_heads, g, hd)
-            out = _attend_dense(qh, ck, cv, bias, kscale=kscale, vscale=vscale)
-            out = out.reshape(*x.shape[:2], cfg.n_heads * hd)
-        out = out.astype(x.dtype)
+                # XLA fold-the-scales oracle: grouped-KV layout over the
+                # whole cache, (..., S, T) scores, per-token scales folded in
+                ck, cv, kscale, vscale = kv_cache.attend_view(fmt, new_cache)
+                t = ck.shape[1]
+                k_pos = jnp.arange(t)
+                bias = _mask_bias(q_pos, k_pos, causal, window, valid)
+                if bias.ndim == 2:
+                    bias = bias[None, None, None]  # (1,1,1,S,T)
+                else:
+                    bias = bias[:, None, None]  # (B,1,1,S,T)
+                qh = q.reshape(*q.shape[:2], cfg.n_kv_heads, g, hd)
+                out = _attend_dense(qh, ck, cv, bias, kscale=kscale,
+                                    vscale=vscale)
+                out = out.reshape(*x.shape[:2], cfg.n_heads * hd)
+            out = out.astype(x.dtype)
         return dense(p["wo"], out, f"{path}/wo", ctx), new_cache
 
     # in-chunk self-attention tail: a full-prompt prefill (cache written,
@@ -323,7 +326,8 @@ def attention(
         and getattr(cfg, "flash_prefill", False)
         and _sh.kernels_routable()
     ):
-        out = _flash_self_path(q, k, v, window, cfg).astype(x.dtype)
+        with jax.named_scope("attention"):
+            out = _flash_self_path(q, k, v, window, cfg).astype(x.dtype)
         return dense(p["wo"], out, f"{path}/wo", ctx), new_cache
 
     # training / prefill: repeat KV to full heads so the head axis shards
@@ -335,16 +339,16 @@ def attention(
     k = _sh.constrain(k, ("batch", None, "heads", None))
     v = _sh.constrain(v, ("batch", None, "heads", None))
     t = k.shape[1]
-    if t > chunk:
-        out = _attend_chunked(q, k, v, q_pos, causal, window, chunk)
-    else:
-        k_pos = jnp.arange(t)
-        if causal or window is not None:
-            bias = _mask_bias(q_pos, k_pos, causal, window)
-            bias = bias[None] if bias.ndim == 2 else bias[:, None]
+    with jax.named_scope("attention"):
+        if t > chunk:
+            out = _attend_chunked(q, k, v, q_pos, causal, window, chunk)
         else:
-            bias = jnp.zeros((), jnp.float32)
-        out = _attend_dense_mha(q, k, v, bias)
-
-    out = out.reshape(*x.shape[:2], cfg.n_heads * hd).astype(x.dtype)
+            k_pos = jnp.arange(t)
+            if causal or window is not None:
+                bias = _mask_bias(q_pos, k_pos, causal, window)
+                bias = bias[None] if bias.ndim == 2 else bias[:, None]
+            else:
+                bias = jnp.zeros((), jnp.float32)
+            out = _attend_dense_mha(q, k, v, bias)
+        out = out.reshape(*x.shape[:2], cfg.n_heads * hd).astype(x.dtype)
     return dense(p["wo"], out, f"{path}/wo", ctx), new_cache

@@ -44,8 +44,15 @@ def dense(
 
     ``act`` ("silu"/"gelu"/"relu") rides into the PTQ kernel epilogue on
     fused backends; on the fp/QAT paths it is applied after the bias, so all
-    modes compute the same function.
+    modes compute the same function.  Its device ops carry the scope
+    ``qdense/<leaf of path>`` (``qdense/wq``, ``qdense/lm_head``).
     """
+    with jax.named_scope(f"qdense/{path.rsplit('/', 1)[-1]}"):
+        return _dense(p, x, path, ctx, act)
+
+
+def _dense(p: Params, x: jax.Array, path: str, ctx: QuantCtx,
+           act: Optional[str]) -> jax.Array:
     w = p["w"]
     if ctx.observer is not None:  # calibration pass: record this site's range
         observe_site(ctx.observer, path, x)

@@ -195,49 +195,50 @@ def capacity(n_tokens: int, top_k: int, n_experts: int, factor: float) -> int:
 
 def _dispatch_chunk(p, experts, xt: jax.Array, path: str, cfg, ctx: QuantCtx, buf_axes):
     """Route one chunk of tokens (tc, d) through the (pre-quantized) experts."""
-    tc, d = xt.shape
-    e, k = cfg.n_experts, cfg.top_k
-    c = capacity(tc, k, e, cfg.capacity_factor)
+    with jax.named_scope("moe_dispatch"):
+        tc, d = xt.shape
+        e, k = cfg.n_experts, cfg.top_k
+        c = capacity(tc, k, e, cfg.capacity_factor)
 
-    logits = dense(p["router"], xt, f"{path}/router", ctx).astype(jnp.float32)
-    probs = jax.nn.softmax(logits, axis=-1)  # (tc, E)
-    top_vals, top_ids = jax.lax.top_k(probs, k)
-    top_vals = top_vals / jnp.sum(top_vals, axis=-1, keepdims=True)
+        logits = dense(p["router"], xt, f"{path}/router", ctx).astype(jnp.float32)
+        probs = jax.nn.softmax(logits, axis=-1)  # (tc, E)
+        top_vals, top_ids = jax.lax.top_k(probs, k)
+        top_vals = top_vals / jnp.sum(top_vals, axis=-1, keepdims=True)
 
-    flat_ids = top_ids.reshape(-1)  # (tc*k,)
-    flat_gate = top_vals.reshape(-1)
-    flat_src = jnp.arange(tc * k, dtype=jnp.int32) // k
-    order = jnp.argsort(flat_ids)
-    sorted_ids = flat_ids[order]
-    sorted_src = flat_src[order]
-    rank = jnp.arange(tc * k, dtype=jnp.int32) - jnp.searchsorted(
-        sorted_ids, sorted_ids, side="left"
-    ).astype(jnp.int32)
-    keep = rank < c
-    # out-of-bounds scatter indices are dropped by XLA => capacity overflow
-    dest = jnp.where(keep, sorted_ids * c + rank, e * c)
+        flat_ids = top_ids.reshape(-1)  # (tc*k,)
+        flat_gate = top_vals.reshape(-1)
+        flat_src = jnp.arange(tc * k, dtype=jnp.int32) // k
+        order = jnp.argsort(flat_ids)
+        sorted_ids = flat_ids[order]
+        sorted_src = flat_src[order]
+        rank = jnp.arange(tc * k, dtype=jnp.int32) - jnp.searchsorted(
+            sorted_ids, sorted_ids, side="left"
+        ).astype(jnp.int32)
+        keep = rank < c
+        # out-of-bounds scatter indices are dropped by XLA => capacity overflow
+        dest = jnp.where(keep, sorted_ids * c + rank, e * c)
 
-    buf = jnp.zeros((e * c, d), xt.dtype).at[dest].set(
-        xt[sorted_src], mode="drop"
-    )
-    use_ep = _use_ep(experts, e, c, ctx)
-    xb = buf.reshape(e, c, d)
-    if not use_ep:  # EP: shard_map's capacity-sharded in_spec IS the layout
-        xb = sharding.constrain(xb, buf_axes)
+        buf = jnp.zeros((e * c, d), xt.dtype).at[dest].set(
+            xt[sorted_src], mode="drop"
+        )
+        use_ep = _use_ep(experts, e, c, ctx)
+        xb = buf.reshape(e, c, d)
+        if not use_ep:  # EP: shard_map's capacity-sharded in_spec IS the layout
+            xb = sharding.constrain(xb, buf_axes)
 
-    yb = _expert_ffn(experts, xb, path, ctx, buf_axes)
-    # combine in the model dtype: the gather/scatter-add below crosses the
-    # expert->token sharding boundary, so its collectives move these bytes
-    # (f32 here doubled the MoE collective term -- Perf iteration B4)
-    yb = yb.astype(xt.dtype)
-    if not use_ep:  # EP: the combine all-to-all already ran inside shard_map
-        yb = sharding.constrain(yb, buf_axes)
+        yb = _expert_ffn(experts, xb, path, ctx, buf_axes)
+        # combine in the model dtype: the gather/scatter-add below crosses the
+        # expert->token sharding boundary, so its collectives move these bytes
+        # (f32 here doubled the MoE collective term -- Perf iteration B4)
+        yb = yb.astype(xt.dtype)
+        if not use_ep:  # EP: the combine all-to-all already ran inside shard_map
+            yb = sharding.constrain(yb, buf_axes)
 
-    vals = yb.reshape(e * c, d).at[dest].get(
-        mode="fill", fill_value=0
-    ) * flat_gate[order][:, None].astype(xt.dtype)
-    out = jnp.zeros((tc, d), xt.dtype).at[sorted_src].add(vals)
-    return sharding.constrain(out, ("batch", None))
+        vals = yb.reshape(e * c, d).at[dest].get(
+            mode="fill", fill_value=0
+        ) * flat_gate[order][:, None].astype(xt.dtype)
+        out = jnp.zeros((tc, d), xt.dtype).at[sorted_src].add(vals)
+        return sharding.constrain(out, ("batch", None))
 
 
 def moe_layer(p, x: jax.Array, path: str, cfg, ctx: QuantCtx) -> jax.Array:

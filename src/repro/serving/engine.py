@@ -54,6 +54,7 @@ from typing import Any, Deque, Dict, List, Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.serving import health as health_mod
 from repro.serving.faults import FaultInjector
@@ -196,14 +197,16 @@ class _EngineBase:
             rows = jnp.arange(last.shape[0], dtype=jnp.int32)[:, None]
             last = jnp.where(rows == fault_slot, fault_val, last)
             key, sub = jax.random.split(key)
-            toks = sample(sub, last, sampler)
+            with jax.named_scope("sample"):
+                toks = sample(sub, last, sampler)
             # numerical guardrail: ONE fused reduction over the tick's
             # logits -> per-slot poison bitflags, stacked with the sampled
             # tokens so flags ride the existing single host sync
-            if guardrails:
-                flags = health_mod.poison_flags(last, sat_limit)
-            else:
-                flags = jnp.zeros_like(toks)
+            with jax.named_scope("guardrail"):
+                if guardrails:
+                    flags = health_mod.poison_flags(last, sat_limit)
+                else:
+                    flags = jnp.zeros_like(toks)
             return jnp.stack([toks, flags]), key, cache
 
         # donate the cache: the decode step's masked writes update it in
@@ -215,10 +218,12 @@ class _EngineBase:
                 # pin the output layout so a donated sharded cache keeps the
                 # serving sharding across insert dispatches
                 jit_kw["out_shardings"] = self._cache_sharding
+
+            def _insert_fn(cache, prefix, slot):
+                return api.insert(cache, prefix, slot)
+
             self._insert_step = jax.jit(
-                lambda cache, prefix, slot: api.insert(cache, prefix, slot),
-                donate_argnums=(0,),
-                **jit_kw,
+                _insert_fn, donate_argnums=(0,), **jit_kw
             )
         else:
             self._insert_step = None
@@ -352,16 +357,23 @@ class _EngineBase:
         return completed
 
     def step(self) -> List[Request]:
-        """One engine step: sweep deadlines, dispatch one stage/tick, feed
-        the watchdog and overload controller.  Returns requests completed
-        by this step (finished, expired, or failed)."""
-        t0 = self._clock()
-        completed = self._expire_deadlines()
-        tick0 = self._tick
-        completed.extend(self._step_impl())
-        if self._tick != tick0:  # a real dispatch happened: time it
-            self.watchdog.observe(self._clock() - t0)
-        self._overload_ctl.update(queue_depth=len(self.queue))
+        """One engine step: sweep deadlines, admit, choose and dispatch one
+        stage/tick, feed the watchdog and overload controller.  Returns
+        requests completed by this step (finished, expired, or failed).
+
+        Profiler spans (``docs/SERVING.md``, "Tracing"): ``engine.step``
+        around it all, ``engine.schedule`` around the sweep, admission and
+        the choice; the dispatch adds its own."""
+        with TraceAnnotation("engine.step"):
+            t0 = self._clock()
+            with TraceAnnotation("engine.schedule"):
+                completed = self._expire_deadlines()
+                action = self._schedule()
+            tick0 = self._tick
+            completed.extend(self._step_impl(action))
+            if self._tick != tick0:  # a real dispatch happened: time it
+                self.watchdog.observe(self._clock() - t0)
+            self._overload_ctl.update(queue_depth=len(self.queue))
         return completed
 
     def leftover(self) -> Dict[str, List[Request]]:
@@ -401,11 +413,14 @@ class _EngineBase:
         """Reserve slot ``s`` for ``req``: reset host state, clear the
         slot's device cache row (stale SSM/recurrent state is NOT masked by
         positions the way stale KV rows are), and stamp admission."""
-        self._reset_slot(s)
-        self._clear_slot_cache(s)
-        req.admitted_tick = self._tick
-        req.prefill_start_t = self._clock()
-        self.slot_req[s] = req
+        wait = 0.0 if req.submit_t is None else self._clock() - req.submit_t
+        with TraceAnnotation("engine.admit", uid=req.uid, slot=s,
+                             queue_wait_ms=wait * 1e3):
+            self._reset_slot(s)
+            self._clear_slot_cache(s)
+            req.admitted_tick = self._tick
+            req.prefill_start_t = self._clock()
+            self.slot_req[s] = req
 
     def _clear_slot_cache(self, s: int) -> None:
         """Zero slot ``s``'s rows of the decode cache via the insert path.
@@ -619,7 +634,12 @@ class _EngineBase:
     def _abort_inflight(self) -> None:
         """Engine-specific teardown of partially-prefilled state (drain)."""
 
-    def _step_impl(self) -> List[Request]:  # pragma: no cover - abstract
+    def _schedule(self) -> str:  # pragma: no cover - abstract
+        """Admit what the engine can and choose this step's dispatch:
+        ``"prefill"``, ``"generate"`` or ``"idle"``."""
+        raise NotImplementedError
+
+    def _step_impl(self, action: str) -> List[Request]:  # pragma: no cover
         raise NotImplementedError
 
     # -- introspection ------------------------------------------------------
@@ -682,10 +702,14 @@ class ServingEngine(_EngineBase):
                 self.slot_cursor[s] = 1  # token 0 goes in this tick
                 self.next_token[s] = req.prompt[0]
 
-    def _step_impl(self) -> List[Request]:
-        """One lockstep tick over all slots; returns requests completed."""
+    def _schedule(self) -> str:
         self._admit()
-        if not any(r is not None for r in self.slot_req):
+        return "generate" if any(r is not None for r in self.slot_req) \
+            else "idle"
+
+    def _step_impl(self, action: str) -> List[Request]:
+        """One lockstep tick over all slots; returns requests completed."""
+        if action == "idle":
             return []
         self._tick += 1
         fault_slot, fault_val = self._draw_fault()
@@ -755,18 +779,18 @@ class StagedEngine(_EngineBase):
         self._last_action = "generate"
         self.counts = {"prefill_chunks": 0, "generate_ticks": 0, "inserts": 0}
         if api.prefill_chunk is not None:
-            self._prefill_step = jax.jit(
-                lambda p, t, start, c: api.prefill_chunk(p, t, start, c),
-                donate_argnums=(3,),
-            )
+            def _prefill_fn(params, tokens, start, cache):
+                return api.prefill_chunk(params, tokens, start, cache)
+
+            self._prefill_step = jax.jit(_prefill_fn, donate_argnums=(3,))
         else:
             # fallback chunked prefill: budgeted per-token decode into the
             # private B=1 cache (recurrent families have no chunk graph)
+            def _pf_decode_fn(params, tokens, pos, cache):
+                return api.decode(params, tokens, pos, cache)
+
             self._prefill_step = None
-            self._pf_decode = jax.jit(
-                lambda p, t, pos, c: api.decode(p, t, pos, c),
-                donate_argnums=(3,),
-            )
+            self._pf_decode = jax.jit(_pf_decode_fn, donate_argnums=(3,))
 
         guardrails = self.health.guardrails
         sat_limit = float(2.0 ** self.health.sat_exponent)
@@ -774,13 +798,15 @@ class StagedEngine(_EngineBase):
         def _first_token(key, logits):
             key, sub = jax.random.split(key)
             last = logits[:, -1, :].astype(jnp.float32)
-            toks = sample(sub, last, self.sampler)
+            with jax.named_scope("sample"):
+                toks = sample(sub, last, self.sampler)
             # same fused guardrail as the decode tick: a poisoned prefill
             # must be caught before its first token is served
-            if guardrails:
-                flags = health_mod.poison_flags(last, sat_limit)
-            else:
-                flags = jnp.zeros_like(toks)
+            with jax.named_scope("guardrail"):
+                if guardrails:
+                    flags = health_mod.poison_flags(last, sat_limit)
+                else:
+                    flags = jnp.zeros_like(toks)
             return jnp.stack([toks, flags]), key
 
         self._first_token = jax.jit(_first_token)
@@ -832,19 +858,21 @@ class StagedEngine(_EngineBase):
             self._pf = None
         super()._abort_slot(s)
 
-    def _step_impl(self) -> List[Request]:
-        """Dispatch one stage (prefill chunk | generate tick); returns
-        requests completed by this dispatch."""
+    def _schedule(self) -> str:
         self._start_prefill()
         # graceful degradation: under overload, protect running requests'
         # TPOT -- force decode-priority regardless of the configured policy
         policy = "decode" if self._overload_ctl.overload else self.sched.policy
-        action = next_action(
+        return next_action(
             policy,
             prefill_ready=self._pf is not None,
             decode_ready=self._decode_ready(),
             last=self._last_action,
         )
+
+    def _step_impl(self, action: str) -> List[Request]:
+        """Dispatch one stage (prefill chunk | generate tick); returns
+        requests completed by this dispatch."""
         if action == "idle":
             return []
         self._tick += 1
@@ -858,35 +886,48 @@ class StagedEngine(_EngineBase):
         pf = self._pf
         start, size = pf.next_chunk()
         req = pf.req
-        chunk_toks = np.asarray([req.prompt[start : start + size]], np.int32)
         out_dev = None
-        with self._dispatch():
-            if self._prefill_step is not None:
-                logits, pf.cache = self._prefill_step(
-                    self.params, jnp.asarray(chunk_toks), jnp.int32(start),
-                    pf.cache,
-                )
-            else:
-                for j in range(size):
-                    logits, pf.cache = self._pf_decode(
-                        self.params, jnp.asarray(chunk_toks[:, j : j + 1]),
-                        jnp.int32(start + j), pf.cache,
+        with TraceAnnotation("engine.prefill", uid=req.uid, start=start,
+                             size=size, fill=start + size,
+                             capacity=self.max_len):
+            chunk_toks = np.asarray([req.prompt[start : start + size]],
+                                    np.int32)
+            with self._dispatch():
+                if self._prefill_step is not None:
+                    logits, pf.cache = self._prefill_step(
+                        self.params, jnp.asarray(chunk_toks),
+                        jnp.int32(start), pf.cache,
                     )
-            pf.advance(size)
-            self.counts["prefill_chunks"] += 1
-            if pf.complete:
-                # first generated token comes from the final chunk's logits;
-                # the finished prefix moves into the reserved decode slot
-                out_dev, self.key = self._first_token(self.key, logits)
+                else:
+                    for j in range(size):
+                        logits, pf.cache = self._pf_decode(
+                            self.params, jnp.asarray(chunk_toks[:, j : j + 1]),
+                            jnp.int32(start + j), pf.cache,
+                        )
+                pf.advance(size)
+                self.counts["prefill_chunks"] += 1
+                if pf.complete:
+                    # first generated token comes from the final chunk's
+                    # logits
+                    out_dev, self.key = self._first_token(self.key, logits)
+        if out_dev is None:
+            return []
+        # the finished prefix moves into the reserved decode slot
+        with TraceAnnotation("engine.insert", uid=req.uid, slot=pf.slot):
+            with self._dispatch():
                 self.cache = self._insert_step(
                     self.cache, pf.cache, jnp.int32(pf.slot)
                 )
-                self.counts["inserts"] += 1
-        if out_dev is None:
-            return []
-        out = np.asarray(out_dev)  # the one host sync
-        tok, flag = int(out[0, 0]), int(out[1, 0])
-        s = pf.slot
+            self.counts["inserts"] += 1
+        with TraceAnnotation("engine.sync"):
+            out = np.asarray(out_dev)  # the one host sync
+        with TraceAnnotation("engine.commit"):
+            return self._commit_first_token(pf, int(out[0, 0]),
+                                            int(out[1, 0]))
+
+    def _commit_first_token(self, pf: PrefillTask, tok: int,
+                            flag: int) -> List[Request]:
+        req, s = pf.req, pf.slot
         self._pf = None
         if flag:  # poisoned prefill: contain before serving its first token
             dead = self._quarantine(s, req, flag)
@@ -901,19 +942,32 @@ class StagedEngine(_EngineBase):
         return []
 
     def _generate_dispatch(self) -> List[Request]:
-        fault_slot, fault_val = self._draw_fault()
-        tokens, pos = self._device_operands()
-        with self._dispatch():
-            out, self.key, self.cache = self._decode_step(
-                self.params, tokens, pos, self.cache, self.key,
-                fault_slot, fault_val,
-            )
-        out = np.asarray(out)  # the ONE host sync per tick
-        sampled, flags = out[0], out[1]
-        self.counts["generate_ticks"] += 1
-
-        completed: List[Request] = []
         reserved = self._pf.slot if self._pf is not None else None
+        live = [s for s, r in enumerate(self.slot_req)
+                if r is not None and s != reserved]
+        # rows served, and the tokens their cache rows hold after this
+        # tick's write (each live row writes at its slot_pos)
+        with TraceAnnotation(
+            "engine.generate", tick=self._tick, rows=len(live),
+            slots=self.n_slots, fill=int(self.slot_pos[live].sum()) + len(live),
+            capacity=self.n_slots * self.max_len,
+        ):
+            fault_slot, fault_val = self._draw_fault()
+            tokens, pos = self._device_operands()
+            with self._dispatch():
+                out, self.key, self.cache = self._decode_step(
+                    self.params, tokens, pos, self.cache, self.key,
+                    fault_slot, fault_val,
+                )
+        with TraceAnnotation("engine.sync"):
+            out = np.asarray(out)  # the ONE host sync per tick
+        self.counts["generate_ticks"] += 1
+        with TraceAnnotation("engine.commit"):
+            return self._commit_tick(out[0], out[1], reserved)
+
+    def _commit_tick(self, sampled: np.ndarray, flags: np.ndarray,
+                     reserved: Optional[int]) -> List[Request]:
+        completed: List[Request] = []
         for s, req in enumerate(self.slot_req):
             if req is None or s == reserved:
                 continue  # idle or mid-prefill: pad row, output discarded

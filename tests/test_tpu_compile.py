@@ -33,6 +33,9 @@ D_MODEL, D_FF, N_KV_HEADS, GQA_GROUP, HEAD_DIM = 3072, 8192, 8, 3, 128
 GROUP = 16  # cluster size chip_smoke.py serves
 MAX_LEN, SLOTS, CHUNK = 1024, 8, 256
 FUSED_FORMATS = [f for f in format_names() if get_format(f).fused_kernel]
+# the weight format each fused kernel is named after (``fused_qmm_<fmt>``):
+# mx runs the int8 kernels
+KERNEL_FMT = {"mx": "int8"}
 # (K, N, epilogue activation): the gate projection and the down projection
 SITES = [(D_MODEL, D_FF, "silu"), (D_FF, D_MODEL, None)]
 
@@ -89,6 +92,7 @@ def test_fused_qdense_compiles(one_chip, monkeypatch, fmt, k, n, act, m):
     step = jax.jit(lambda x, qt: backends.qdense(x, qt, act=act, backend="pallas"))
     text = step.lower(x, _on(one_chip, qt)).compile().as_text()
     assert "tpu_custom_call" in text
+    assert f"%fused_qmm_{KERNEL_FMT.get(fmt, fmt)}" in text
 
 
 @pytest.mark.parametrize(
@@ -100,7 +104,9 @@ def test_fused_qdense_compiles(one_chip, monkeypatch, fmt, k, n, act, m):
 def test_quantize_rows_compiles(one_chip, m, d, dtype):
     x = jax.ShapeDtypeStruct((m, d), dtype, sharding=one_chip)
     fn = jax.jit(lambda x: quantize_rows(x, interpret=False))
-    assert "tpu_custom_call" in fn.lower(x).compile().as_text()
+    text = fn.lower(x).compile().as_text()
+    assert "tpu_custom_call" in text
+    assert "%quantize_rows" in text
 
 
 def _cache_leaves(fmt, b):
@@ -136,6 +142,7 @@ def test_flash_attend_compiles(one_chip, fmt, b, s):
     args = _on(one_chip, (q, k, v, ke, ve, rows, rows, win))
     text = jax.jit(attend).lower(*args).compile().as_text()
     assert "tpu_custom_call" in text
+    assert "%flash_attend" in text
 
 
 @pytest.fixture(scope="module")
@@ -173,5 +180,10 @@ def test_sharded_qdense_compiles(one_chip, mesh_2x2, monkeypatch, site, k, n):
     finally:
         rules.set_activation_mesh(None)
     assert "tpu_custom_call" in text
+    # the kernels by name: with K over 'model' the rows are quantized
+    # alone, then the unfused matmul runs
+    names = (("%quantize_rows", "%packed_qmm_ternary") if site.endswith("down")
+             else ("%fused_qmm_ternary",))
+    assert all(name in text for name in names)
     if site.endswith("down"):  # K over 'model': the partials are summed
         assert "all-reduce" in text
