@@ -26,8 +26,13 @@ position q_start[b] + qi*bq + r//G, and a key column is live iff
 
 Query rows are assumed CONTIGUOUS from ``q_start`` (position q_start + s
 for chunk row s) -- exactly what ``transformer.prefill_chunk`` and the
-decode step produce.  Fully-masked tiles still run (the grid is static)
-but contribute zero through the -inf bias.
+decode step produce.  The grid is static, but a (batch row, query block)
+reads and computes only the KV tiles that hold a live key for some row of
+the block (``live_kv_tiles``): the K/V index maps clamp the KV step to
+that range, so the steps outside it name the block already in VMEM and
+issue no DMA, and the body skips them.  The result is the same as running
+every tile: a tile after the last live key adds p = 0 with corr = 1, and
+one before the first (sliding window) is wiped by corr = 0.
 
 The XLA fold-the-scales path in ``models/attention.py::_attend_dense``
 stays as the oracle; ``tests/test_flash_prefill.py`` holds the S > 1
@@ -40,6 +45,7 @@ import functools
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -79,6 +85,47 @@ def _dequant_tile(ref, eref, h: int, fmt: str, bk: int, hd: int) -> jax.Array:
     return codes * dfp.exp2i(e_tok)
 
 
+def live_kv_tiles(q_start, valid, window, qi, *, bq: int, bk: int, xp=jnp):
+    """First and last KV tile holding a live key for query block ``qi``.
+
+    The block's rows sit at q_start + qi*bq .. q_start + qi*bq + bq - 1; a
+    key is live for one of them iff k_pos < valid, k_pos <= the last row
+    (causal) and k_pos > the first row - window.  ``xp=jnp`` evaluates it
+    on traced scalars (index maps, kernel body); ``xp=np`` on host integers
+    or arrays (``live_kv_steps``).  The range is empty when first > last.
+    """
+    lo = q_start + qi * bq
+    first = xp.maximum(lo - window + 1, 0) // bk
+    last = xp.minimum(valid - 1, lo + bq - 1) // bk
+    return first, last
+
+
+def live_kv_steps(q_start, valid, window: int, *, s: int, g: int, t: int,
+                  fmt: str) -> tuple:
+    """(live, grid): the KV grid steps ``flash_attend`` runs its body on
+    for rows at ``q_start`` / ``valid`` (host arrays, one entry a row) with
+    S queries each, summed over rows and query blocks, and the grid's KV
+    steps (B * S/bq * T/bk)."""
+    bq, bk = pick_q_block(s, g), pick_kv_block(t, fmt)
+    q_start = np.asarray(q_start, np.int64).reshape(-1, 1)
+    valid = np.asarray(valid, np.int64).reshape(-1, 1)
+    qi = np.arange(s // bq)[None, :]
+    first, last = live_kv_tiles(q_start, valid, window, qi, bq=bq, bk=bk,
+                                xp=np)
+    live = int(np.maximum(last - first + 1, 0).sum())
+    return live, q_start.shape[0] * (s // bq) * (t // bk)
+
+
+def kv_tile(bi, qi, ji, qs_ref, vl_ref, win_ref, *, bq: int, bk: int):
+    """The KV tile grid step (bi, qi, ji) loads: ``ji`` clamped to the
+    block's live tiles.  A dead step names its neighbouring live tile,
+    whose block is already in VMEM, so the pipeline issues no DMA for it;
+    the outer clamp keeps an empty range (valid == 0) on the grid."""
+    first, last = live_kv_tiles(qs_ref[bi], vl_ref[bi], win_ref[0], qi,
+                                bq=bq, bk=bk)
+    return jnp.maximum(jnp.minimum(jnp.maximum(ji, first), last), 0)
+
+
 def _kernel(qs_ref, vl_ref, win_ref, *refs, fmt, kh, rows, bk, g, hd, scale):
     if fmt == "kv_bf16":
         q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref = refs
@@ -89,6 +136,9 @@ def _kernel(qs_ref, vl_ref, win_ref, *refs, fmt, kh, rows, bk, g, hd, scale):
     b_idx = pl.program_id(0)
     q_idx = pl.program_id(1)
     kv_idx = pl.program_id(2)
+    valid, win = vl_ref[b_idx], win_ref[0]
+    first, last = live_kv_tiles(qs_ref[b_idx], valid, win, q_idx,
+                                bq=rows // g, bk=bk)
 
     @pl.when(kv_idx == 0)
     def _init():
@@ -96,35 +146,36 @@ def _kernel(qs_ref, vl_ref, win_ref, *refs, fmt, kh, rows, bk, g, hd, scale):
         l_ref[...] = jnp.zeros_like(l_ref)
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    # all G heads of a kv group ride as interleaved rows: row r of query
-    # block q_idx is chunk position (q_idx * rows + r) // G
-    k_pos = kv_idx * bk + jax.lax.broadcasted_iota(jnp.int32, (1, bk), 1)
-    row = q_idx * rows + jax.lax.broadcasted_iota(jnp.int32, (rows, 1), 0)
-    q_pos = qs_ref[b_idx] + row // g  # (rows, 1) absolute
-    valid, win = vl_ref[b_idx], win_ref[0]
-    ok = (k_pos < valid) & (k_pos <= q_pos) & (q_pos - k_pos < win)
+    @pl.when((first <= kv_idx) & (kv_idx <= last))
+    def _attend():
+        # all G heads of a kv group ride as interleaved rows: row r of
+        # query block q_idx is chunk position (q_idx * rows + r) // G
+        k_pos = kv_idx * bk + jax.lax.broadcasted_iota(jnp.int32, (1, bk), 1)
+        row = q_idx * rows + jax.lax.broadcasted_iota(jnp.int32, (rows, 1), 0)
+        q_pos = qs_ref[b_idx] + row // g  # (rows, 1) absolute
+        ok = (k_pos < valid) & (k_pos <= q_pos) & (q_pos - k_pos < win)
 
-    for h in range(kh):  # the block holds every kv head of these tokens
-        q = q_ref[h].astype(jnp.float32) * scale  # (rows, hd)
-        kf = _dequant_tile(k_ref, ke_ref, h, fmt, bk, hd)  # (bk, hd)
-        s = jax.lax.dot_general(
-            q, kf, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )  # (rows, bk)
-        s = jnp.where(ok, s, NEG_INF)
+        for h in range(kh):  # the block holds every kv head of these tokens
+            q = q_ref[h].astype(jnp.float32) * scale  # (rows, hd)
+            kf = _dequant_tile(k_ref, ke_ref, h, fmt, bk, hd)  # (bk, hd)
+            s = jax.lax.dot_general(
+                q, kf, (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32,
+            )  # (rows, bk)
+            s = jnp.where(ok, s, NEG_INF)
 
-        m_prev, l_prev = m_ref[h], l_ref[h]  # (rows, 1)
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
-        p = jnp.exp(s - m_new)
-        corr = jnp.exp(m_prev - m_new)
-        vf = _dequant_tile(v_ref, ve_ref, h, fmt, bk, hd)  # (bk, hd)
-        pv = jax.lax.dot_general(
-            p, vf, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
-        l_ref[h] = l_prev * corr + jnp.sum(p, axis=-1, keepdims=True)
-        acc_ref[h] = acc_ref[h] * corr + pv
-        m_ref[h] = m_new
+            m_prev, l_prev = m_ref[h], l_ref[h]  # (rows, 1)
+            m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+            p = jnp.exp(s - m_new)
+            corr = jnp.exp(m_prev - m_new)
+            vf = _dequant_tile(v_ref, ve_ref, h, fmt, bk, hd)  # (bk, hd)
+            pv = jax.lax.dot_general(
+                p, vf, (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32,
+            )
+            l_ref[h] = l_prev * corr + jnp.sum(p, axis=-1, keepdims=True)
+            acc_ref[h] = acc_ref[h] * corr + pv
+            m_ref[h] = m_new
 
     @pl.when(kv_idx == pl.num_programs(2) - 1)
     def _finalize():
@@ -205,16 +256,16 @@ def flash_attend(
     q_spec = pl.BlockSpec(
         (None, kh, rows, hd), lambda bi, qi, ji, *_: (bi, 0, qi, 0)
     )
-    kv_spec = pl.BlockSpec(
-        (None, bk, kh, k.shape[-1]), lambda bi, qi, ji, *_: (bi, ji, 0, 0)
-    )
+
+    def kv_map(bi, qi, ji, *refs):
+        return bi, kv_tile(bi, qi, ji, *refs, bq=bq, bk=bk), 0, 0
+
+    kv_spec = pl.BlockSpec((None, bk, kh, k.shape[-1]), kv_map)
     in_specs = [q_spec, kv_spec, kv_spec]
     args = [qg, k, v]
     if fmt != "kv_bf16":
         eb = bk if fmt == "kv_int8" else bk // MX_KV_BLOCK
-        e_spec = pl.BlockSpec(
-            (None, eb, kh, 1), lambda bi, qi, ji, *_: (bi, ji, 0, 0)
-        )
+        e_spec = pl.BlockSpec((None, eb, kh, 1), kv_map)
         in_specs += [e_spec, e_spec]
         args += [ke, ve]
 

@@ -56,6 +56,8 @@ import jax.numpy as jnp
 import numpy as np
 from jax.profiler import TraceAnnotation
 
+from repro.kernels.flash_prefill import live_kv_steps
+from repro.models import kv_cache
 from repro.serving import health as health_mod
 from repro.serving.faults import FaultInjector
 from repro.serving.health import HealthConfig, OverloadController, TickWatchdog
@@ -778,6 +780,12 @@ class StagedEngine(_EngineBase):
         self._pf: Optional[PrefillTask] = None
         self._last_action = "generate"
         self.counts = {"prefill_chunks": 0, "generate_ticks": 0, "inserts": 0}
+        cfg = api.cfg
+        # query heads per kv head and cache format: the flash grid's shape
+        # (families without attention heads have none)
+        self._flash_geom = ((cfg.n_heads // cfg.n_kv_heads,
+                             kv_cache.resolve_kv_fmt(cfg))
+                            if cfg.n_kv_heads else None)
         if api.prefill_chunk is not None:
             def _prefill_fn(params, tokens, start, cache):
                 return api.prefill_chunk(params, tokens, start, cache)
@@ -882,6 +890,18 @@ class StagedEngine(_EngineBase):
         return self._generate_dispatch()
 
     # -- stages ------------------------------------------------------------
+    def _kv_tiles(self, q_start, valid, s: int) -> Dict[str, int]:
+        """Span arguments: the flash grid's KV steps per full-attention
+        layer that run live for rows at ``q_start`` / ``valid`` with S
+        queries each (``kv_tiles``), and all of its KV steps
+        (``kv_tiles_grid``)."""
+        if self._flash_geom is None:
+            return {"kv_tiles": 0, "kv_tiles_grid": 0}
+        g, fmt = self._flash_geom
+        live, grid = live_kv_steps(q_start, valid, 2**30, s=s, g=g,
+                                   t=self.max_len, fmt=fmt)
+        return {"kv_tiles": live, "kv_tiles_grid": grid}
+
     def _prefill_dispatch(self) -> List[Request]:
         pf = self._pf
         start, size = pf.next_chunk()
@@ -889,7 +909,8 @@ class StagedEngine(_EngineBase):
         out_dev = None
         with TraceAnnotation("engine.prefill", uid=req.uid, start=start,
                              size=size, fill=start + size,
-                             capacity=self.max_len):
+                             capacity=self.max_len,
+                             **self._kv_tiles([start], [start + size], size)):
             chunk_toks = np.asarray([req.prompt[start : start + size]],
                                     np.int32)
             with self._dispatch():
@@ -951,6 +972,8 @@ class StagedEngine(_EngineBase):
             "engine.generate", tick=self._tick, rows=len(live),
             slots=self.n_slots, fill=int(self.slot_pos[live].sum()) + len(live),
             capacity=self.n_slots * self.max_len,
+            # every slot's row runs, at q_start = slot_pos, valid = pos + 1
+            **self._kv_tiles(self.slot_pos, self.slot_pos + 1, 1),
         ):
             fault_slot, fault_val = self._draw_fault()
             tokens, pos = self._device_operands()

@@ -15,13 +15,15 @@ import pytest
 from jax.profiler import ProfileData
 
 from repro import configs
-from repro.models import build_model
+from repro.kernels.flash_prefill import live_kv_steps
+from repro.models import build_model, kv_cache
 from repro.serving import Request, SchedulerConfig, StagedEngine, chunk_plan
 
-CHUNK, MAX_LEN, SLOTS = 8, 64, 2
+CHUNK, MAX_LEN, SLOTS = 8, 256, 2
 # prompts across the chunk boundary; three requests on two slots, so one
-# waits for a slot while the others generate
-LENS, MAX_NEW = (5, 19, 8), (4, 3, 5)
+# waits for a slot while the others generate; the long one reaches the
+# cache's second flash tile (128 tokens)
+LENS, MAX_NEW = (5, 150, 8), (4, 3, 5)
 DISPATCH = ("engine.prefill", "engine.generate")
 
 
@@ -114,6 +116,39 @@ def test_generate_spans_count_ticks_rows_and_fill(served, engine):
         assert args["capacity"] == SLOTS * MAX_LEN
         assert args["fill"] == sum(held for _, held in g)
     assert [s[3]["tick"] for s in gen] == sorted(s[3]["tick"] for s in gen)
+
+
+def test_dispatch_spans_count_live_kv_tiles(served, engine):
+    """``kv_tiles`` / ``kv_tiles_grid`` are the flash grid's live and total
+    KV steps for the rows a dispatch runs: every slot at its position in a
+    tick (idle and reserved slots at 0), the chunk at its start in a
+    prefill."""
+    _, steps, spans = served
+    cfg = engine.api.cfg
+    group, fmt = cfg.n_heads // cfg.n_kv_heads, kv_cache.resolve_kv_fmt(cfg)
+
+    def counted(q_start, valid, s):
+        live, grid = live_kv_steps(q_start, valid, 2**30, s=s, g=group,
+                                   t=MAX_LEN, fmt=fmt)
+        return {"kv_tiles": live, "kv_tiles_grid": grid}
+
+    def read(args):
+        return {k: args[k] for k in ("kv_tiles", "kv_tiles_grid")}
+
+    ticks = [g for kind, g in steps if kind == "generate"]
+    gen = _named(spans, "engine.generate")
+    for span, g in zip(gen, ticks):
+        pos = [held - 1 for _, held in g] + [0] * (SLOTS - len(g))
+        assert read(span[3]) == counted(pos, [p + 1 for p in pos], 1)
+    pre = [s[3] for s in _named(spans, "engine.prefill")]
+    for a in pre:
+        assert read(a) == counted([a["start"]], [a["start"] + a["size"]],
+                                  a["size"])
+    for a in [s[3] for s in gen] + pre:
+        assert 0 < a["kv_tiles"] <= a["kv_tiles_grid"]
+    # the long request's rows past token 128 run both tiles, the others one
+    assert {a["kv_tiles"] for a in (s[3] for s in gen)} >= {SLOTS, SLOTS + 1}
+    assert any(a["kv_tiles"] < a["kv_tiles_grid"] for a in pre)
 
 
 def test_prefill_spans_follow_chunk_plan_and_carry_uid(served):

@@ -7,7 +7,10 @@ format quantization error cancels and the comparison isolates the
 kernel's online-softmax math over the packed leaves; only float sum-order
 differences remain (atol 5e-5).
 
-Plus: model-level routing (``cfg.flash_prefill`` toggles the kernel under
+Plus: dead KV tiles are never read (every whole dead tile poisoned, the
+output bit for bit unchanged), the clamped KV index over the whole grid
+against the masking contract and the engine's count of live steps,
+model-level routing (``cfg.flash_prefill`` toggles the kernel under
 real ``prefill_chunk`` dispatches at ragged starts), the in-chunk
 self-attention tail (``api.prefill``), query/KV block selection, and the
 KV_SEQ_SHARD fallback -- flash routing must be cleanly BYPASSED (oracle
@@ -26,7 +29,14 @@ import numpy as np
 import pytest
 
 from repro import configs
-from repro.kernels.flash_prefill import flash_attend, pick_kv_block, pick_q_block
+from repro.kernels.flash_prefill import (
+    flash_attend,
+    kv_tile,
+    live_kv_steps,
+    live_kv_tiles,
+    pick_kv_block,
+    pick_q_block,
+)
 from repro.models import build_model, kv_cache
 from repro.models.attention import _attend_dense, _mask_bias
 
@@ -135,6 +145,133 @@ def test_flash_prefill_ragged_valid_rows():
     bias = _mask_bias(q_pos, jnp.arange(t), True, None, valid)
     want = _attend_dense(q, ck, cv, bias[:, None, None], kscale=ks, vscale=vs)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=5e-5)
+
+
+def _live(q_pos, t, valid, window):
+    """(S, T) live-key mask of one batch row, from the masking contract."""
+    k_pos = np.arange(t)[None, :]
+    q_pos = np.asarray(q_pos)[:, None]
+    win = 2**30 if window is None else window
+    return (k_pos < valid) & (k_pos <= q_pos) & (q_pos - k_pos < win)
+
+
+def _poison_dead_tiles(cache, fmt, starts, s, valid, window, bk):
+    """Copy of ``cache`` with every tile that holds no live key for any row
+    of its batch row poisoned: NaN in kv_bf16, exponent 127 in the quantized
+    planes (127 * 2**127 overflows to inf, and 0 * inf is NaN in p @ v).
+    Returns (cache, number of tiles poisoned)."""
+    t = cache["k"].shape[1]
+    leaves = {n: np.array(x) for n, x in cache.items()}
+    n = 0
+    for b, (st, vl) in enumerate(zip(starts, np.asarray(valid))):
+        live = _live(st + np.arange(s), t, vl, window).any(axis=0)
+        for j in range(t // bk):
+            if live[j * bk:(j + 1) * bk].any():
+                continue
+            n += 1
+            if fmt == "kv_bf16":
+                for name in ("k", "v"):
+                    leaves[name][b, j * bk:(j + 1) * bk] = np.nan
+            else:  # kv_int8: one exponent a token; kv_mx: one per 32
+                per = 32 if fmt == "kv_mx" else 1
+                for name in ("ke", "ve"):
+                    leaves[name][b, j * bk // per:(j + 1) * bk // per] = 127
+    return {k: jnp.asarray(x) for k, x in leaves.items()}, n
+
+
+@pytest.mark.parametrize("fmt", FORMATS)
+@pytest.mark.parametrize(
+    "case", ["decode_ragged", "chunk_ragged", "window_leading"]
+)
+def test_flash_skips_dead_tiles_exactly(fmt, case):
+    """Tiles with no live key are never read: poisoning every whole dead
+    tile leaves the output bit for bit as it was, and within the oracle's
+    tolerance.  Cases: S == 1 rows whose fill stops several tiles short of
+    T; a 16-row chunk in 4 query blocks at a ragged start; a sliding window
+    that leaves the leading tiles dead."""
+    kh, g, hd, t = 2, 2, 8, 128
+    bk = 32 if fmt == "kv_mx" else 16
+    rng = np.random.default_rng(4)
+    if case == "decode_ragged":
+        b, s, window = 3, 1, None
+        valid = np.asarray([5, 40, 70])
+        starts = valid - 1
+    else:
+        b, s = 2, (16 if case == "chunk_ragged" else 8)
+        st = 37 if case == "chunk_ragged" else 100
+        window = None if case == "chunk_ragged" else 24
+        starts, valid = np.full(b, st), np.full(b, st + s)
+    cache = kv_cache.init_cache(_Cfg(kh, hd, fmt), (b,), t)
+    kv = jnp.asarray(rng.normal(size=(b, t, kh, hd)) * 0.5, jnp.float32)
+    cache, _ = kv_cache.write(fmt, cache, kv, kv[:, ::-1], jnp.int32(0))
+    q = jnp.asarray(rng.normal(size=(b, s, kh, g, hd)), jnp.float32)
+    valid_j = jnp.asarray(valid, jnp.int32)
+    win = jnp.asarray(2**30 if window is None else window,
+                      jnp.int32).reshape(1, 1)
+
+    def run(c):
+        return np.asarray(flash_attend(
+            q, c["k"], c["v"], c.get("ke"), c.get("ve"),
+            jnp.asarray(starts, jnp.int32).reshape(b, 1),
+            valid_j.reshape(b, 1), win, fmt=fmt, block_q=8, block_k=bk,
+            interpret=True,
+        ))
+
+    if case == "chunk_ragged":
+        assert s // pick_q_block(s, g, 8) >= 4
+    poisoned, n = _poison_dead_tiles(cache, fmt, starts, s, valid, window, bk)
+    assert n >= 2 * b if case != "decode_ragged" else n >= 5
+    clean, got = run(cache), run(poisoned)
+    np.testing.assert_array_equal(got, clean)
+    ck, cv, ks, vs = kv_cache.attend_view(fmt, cache)
+    q_pos = jnp.asarray(starts)[:, None] + jnp.arange(s)[None, :]
+    bias = _mask_bias(q_pos, jnp.arange(t), True, window, valid_j)
+    want = _attend_dense(q, ck, cv, bias[:, None, None], kscale=ks, vscale=vs)
+    np.testing.assert_allclose(got, np.asarray(want), atol=5e-5)
+
+
+@pytest.mark.parametrize(
+    "starts,valid,window,s,g,fmt",
+    [
+        ([0, 5, 130, 2047], [1, 6, 131, 2048], 2**30, 1, 3, "kv_int8"),
+        ([300], [556], 2**30, 256, 3, "kv_int8"),  # chunk, ragged start
+        ([0], [256], 2**30, 256, 3, "kv_bf16"),  # a first chunk
+        ([1000, 1500], [1064, 1564], 200, 64, 4, "kv_mx"),  # window
+        ([0, 7], [0, 8], 3, 1, 1, "kv_bf16"),  # an empty row (valid == 0)
+    ],
+    ids=["decode", "chunk", "first_chunk", "window", "empty_row"],
+)
+def test_live_kv_tiles_clamp_the_grid(starts, valid, window, s, g, fmt):
+    """Over the whole grid, the clamped KV tile index is non-decreasing in
+    each (row, query block), names each tile that holds a live key exactly
+    once, at the step the body runs, and repeats the step before's tile at
+    every dead step after the first (no new DMA); the steps that run are
+    what ``live_kv_steps`` counts for the engine's spans."""
+    t = 2048
+    bq, bk = pick_q_block(s, g), pick_kv_block(t, fmt)
+    nk, nq = t // bk, s // bq
+    qs, vl = np.asarray(starts), np.asarray(valid)
+    win = np.asarray([window])
+    ran = 0
+    for bi in range(len(starts)):
+        mask = _live(qs[bi] + np.arange(s), t, vl[bi], window)
+        for qi in range(nq):
+            block = mask[qi * bq:(qi + 1) * bq].any(axis=0)
+            tiles = [j for j in range(nk) if block[j * bk:(j + 1) * bk].any()]
+            first, last = live_kv_tiles(qs[bi], vl[bi], window, qi, bq=bq,
+                                        bk=bk, xp=np)
+            runs = [j for j in range(nk) if first <= j <= last]
+            assert runs == tiles
+            seq = [int(kv_tile(bi, qi, ji, qs, vl, win, bq=bq, bk=bk))
+                   for ji in range(nk)]
+            assert all(0 <= x < nk for x in seq)
+            assert seq == sorted(seq)
+            assert [seq[j] for j in runs] == tiles
+            assert all(seq[j] == seq[j - 1] for j in range(1, nk)
+                       if j not in runs)
+            ran += len(runs)
+    assert live_kv_steps(qs, vl, window, s=s, g=g, t=t, fmt=fmt) == (
+        ran, len(starts) * nq * nk)
 
 
 def test_pick_q_block():
